@@ -15,6 +15,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (fig1_wild_convergence, fig2_scaling_partitions,
                fig3_convergence, fig4_strong_scaling, fig5_ablations,
                fig6_solvers, resilience, roofline)
@@ -60,6 +62,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default="BENCH_2.json",
                     help="summary output path ('' disables)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     total = 0
     figures: dict[str, dict] = {}

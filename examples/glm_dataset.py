@@ -16,11 +16,13 @@ import tempfile
 import numpy as np
 
 from repro.api import Session
+from repro.compile_cache import enable_compile_cache
 from repro.core import EngineConfig
 from repro.data import get_spec
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--dataset", default="higgs",
                     help="registry name (higgs, epsilon, "

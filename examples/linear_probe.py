@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import LogisticRegression
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke
 from repro.data.loader import markov_batch
 from repro.launch import steps as steps_lib
@@ -31,6 +32,7 @@ def features(cfg, params, tokens):
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_smoke("smollm-360m")
     params = steps_lib.init_params(cfg, jax.random.PRNGKey(0))
 

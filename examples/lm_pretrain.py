@@ -12,6 +12,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ArchConfig
 from repro.launch.train import train
 
@@ -27,6 +28,7 @@ CFG_100M = ArchConfig(
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=4)

@@ -12,11 +12,13 @@ import numpy as np
 
 from repro.api import (EarlyStopping, GapLogger, LogisticRegression,
                        Session)
+from repro.compile_cache import enable_compile_cache
 from repro.core import EngineConfig
 from repro.data import make_dense_classification
 
 
 def main() -> None:
+    enable_compile_cache()
     # 16k examples x 100 dense features (the paper's Fig-1 shape).
     # Estimators speak sklearn layout: X (n_samples, n_features).
     Xcol, y = make_dense_classification(n=16_384, d=100, seed=0)

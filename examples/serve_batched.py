@@ -8,11 +8,13 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke, list_archs
 from repro.launch.serve import serve
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="recurrentgemma-2b",
                     choices=list_archs())

@@ -57,7 +57,6 @@ def audit_plan(sig, topo, plan) -> list[Finding]:
     if sig.sparse:
         nnz = _round_up(max(sig.nnz, 1), plan.nnz_multiple) \
             if plan.nnz_multiple else sig.nnz
-        d_pad = _round_up(max(sig.d, 8), 8)
         if plan.route == "pallas-sharded":
             if not plan.feature_shard or topo.model_lanes <= 1:
                 emit(f"plan claims route=pallas-sharded without a "
@@ -69,12 +68,13 @@ def audit_plan(sig, topo, plan) -> list[Finding]:
                 B, nnz, d_eff)
             label = f"sharded slice d_loc={d_eff}"
         else:
-            d_eff = d_pad
-            need = sdca_sparse_bucket.vmem_bytes_estimate(B, nnz, d_pad)
-            label = f"replicated d_pad={d_pad}"
-        if d_eff * 4 > topo.v_budget():
+            d_eff = sig.d
+            need = sdca_sparse_bucket.vmem_bytes_estimate(B, nnz, sig.d)
+            label = f"replicated d={sig.d}"
+        v_bytes = sdca_sparse_bucket.v_bytes(d_eff)
+        if v_bytes > topo.v_budget():
             emit(f"{plan.route} plan's resident v ({label}, "
-                 f"{d_eff * 4} B) exceeds the {topo.v_budget()}-byte "
+                 f"{v_bytes} B) exceeds the {topo.v_budget()}-byte "
                  f"resident-v budget")
         if need > topo.total_budget():
             emit(f"{plan.route} plan needs ~{need} B of VMEM for "
@@ -85,11 +85,10 @@ def audit_plan(sig, topo, plan) -> list[Finding]:
         if B_pad > sdca_bucket.MAX_BUCKET:
             emit(f"dense plan bucket={B} exceeds the kernel recursion "
                  f"cap B <= {sdca_bucket.MAX_BUCKET}")
-        d_pad = _round_up(max(sig.d, 8), 8)
-        need = sdca_bucket.vmem_bytes_estimate(B_pad, d_pad)
+        need = sdca_bucket.vmem_bytes_estimate(B_pad, sig.d)
         if need > topo.total_budget():
             emit(f"dense plan needs ~{need} B of VMEM for (B={B_pad}, "
-                 f"d_pad={d_pad}); budget is {topo.total_budget()} B")
+                 f"d={sig.d}); budget is {topo.total_budget()} B")
     return found
 
 

@@ -80,8 +80,8 @@ LIVE_KERNEL_FILES = (
 
 #: Sum-reordering cross-lane reductions: banned anywhere in a
 #: deterministic=True trace (JAX-PSUM-EXCHANGE).  lax.psum_scatter
-#: binds the "reduce_scatter" primitive; under shard_map's
-#: check_rep=True rewrite, lax.psum binds "psum2".
+#: binds the "reduce_scatter" primitive; "psum2" is the name lax.psum
+#: binds under shard_map's replication checking.
 PSUM_PRIMS = frozenset({"psum", "psum2", "reduce_scatter"})
 
 #: Other unordered cross-lane reductions with no ordered twin in the

@@ -12,8 +12,13 @@ for three rules:
   hazard (the PR 1 / PR 6 bug class): inside a shard_map region, a
   scan/while const (a value the loop CLOSES OVER, as opposed to its
   carry or scanned xs) that is integer-typed and tainted by
-  lax.axis_index.  shard_map treats such closures as replicated, so
-  every lane silently runs lane 0's value.
+  lax.axis_index.  shard_map treated such closures as replicated on
+  the jax of PR 1, so every lane silently ran lane 0's value.  On jax
+  0.9 the closed-over form computes per-lane values (a two-lane probe
+  of the fori_loop below gives each lane its own slice), but the rule
+  stays as the guard, and jax 0.9 hoists a carry the body only
+  forwards into such a const — so the legal form is a per-iteration
+  scanned input, not a forwarded carry.
 * JAX-NONDET-PRIM — other unordered cross-lane reductions (pmax/pmin)
   in a deterministic=True trace.
 
@@ -237,9 +242,10 @@ class _Walker:
                     f"{kind} inside shard_map closes over a "
                     f"loop-invariant integer value derived from "
                     f"axis_index (const #{i}, "
-                    f"{getattr(var, 'aval', '?')}); thread it through "
-                    f"the carry or the scanned xs — shard_map "
-                    f"replicates closed-over values across lanes")
+                    f"{getattr(var, 'aval', '?')}); pass it as a "
+                    f"scanned xs input (a carry the body only forwards "
+                    f"is hoisted into a const too) — shard_map may "
+                    f"replicate closed-over values across lanes")
 
 
 def audit_jaxpr(closed, *, deterministic: bool, case: str = "",

@@ -59,13 +59,15 @@ RULES: dict[str, Rule] = {r.id: r for r in (
         JAX_LOOP_CLOSURE, "jaxpr",
         "Inside a shard_map region, no scan/while/fori_loop may close "
         "over a loop-invariant integer value derived from "
-        "lax.axis_index (it must ride in the carry or the scanned "
-        "xs): shard_map treats such closures as replicated and every "
-        "lane runs lane 0's value.",
+        "lax.axis_index (it must ride in the scanned xs; jax 0.9 "
+        "hoists a carry the body only forwards into a const): "
+        "shard_map may treat such closures as replicated, and every "
+        "lane then runs lane 0's value.",
         "PR 1: a fori_loop chunk loop replicated lane 0's visit perm "
         "to every lane (now statically unrolled in engine.run_epoch); "
-        "PR 6: the sharded sparse solver threads its slice offset "
-        "`lo` through the scan carry for the same reason."),
+        "PR 6: the sharded sparse solver threaded its slice offset "
+        "`lo` through the scan carry, which jax 0.9 hoists back into "
+        "a const, so it now rides in the scanned xs."),
     Rule(
         JAX_NONDET_PRIM, "jaxpr",
         "Under deterministic=True no other unordered cross-lane "

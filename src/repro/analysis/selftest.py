@@ -49,12 +49,10 @@ def _mesh():
 def _shmap(inner, out_spec=None):
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = _mesh()
-    f = shard_map(inner, mesh=mesh, in_specs=P("data"),
-                  out_specs=out_spec if out_spec is not None
-                  else P("data"))
+    from repro.core.engine import shard_map
+    f = shard_map(inner, _mesh(), P("data"),
+                  out_spec if out_spec is not None else P("data"))
     return jax.make_jaxpr(f)(jnp.zeros(8))
 
 
@@ -71,7 +69,7 @@ def check_psum_exchange() -> None:
 
 def check_loop_closure() -> None:
     """Un-threaded tainted int in a fori body -> JAX-LOOP-CLOSURE; the
-    carry-threaded twin of the same program must pass (this pair is the
+    xs-threaded twin of the same program must pass (this pair is the
     PR 1 / PR 6 bug class reconstructed minimally — the regression
     test pins it too)."""
     import jax
@@ -84,18 +82,18 @@ def check_loop_closure() -> None:
         return jax.lax.fori_loop(0, 4, body, 0.0)[None]
 
     def threaded(x):
+        import jax.numpy as jnp
         lane = jax.lax.axis_index("data")
         lo = lane * 4
-        def body(i, carry):
-            acc, lo = carry
-            return acc + x[lo + i], lo      # threaded through the carry
-        return jax.lax.fori_loop(0, 4, body, (0.0, lo))[0][None]
+        def body(acc, lo_i):                # a per-iteration input
+            return acc + x[lo_i], None
+        return jax.lax.scan(body, 0.0, lo + jnp.arange(4))[0][None]
 
     _expect(jaxpr_audit.audit_jaxpr(_shmap(buggy), deterministic=True),
             rules.JAX_LOOP_CLOSURE, "closed-over axis-derived offset")
     _expect_clean(
         jaxpr_audit.audit_jaxpr(_shmap(threaded), deterministic=True),
-        "carry-threaded twin")
+        "xs-threaded twin")
 
 
 def check_nondet_prim() -> None:
@@ -117,16 +115,17 @@ def check_plan_budget() -> None:
     geometry routed honestly (through candidate enumeration) passes."""
     from repro.core.planner import (SolverPlan, Topology,
                                     WorkloadSignature, static_plan)
-    # (B=16, nnz=512): match tensor alone is 16*512*512*5 B ~ 20 MiB
-    sig = WorkloadSignature(n=4096, d=64, nnz=512, sparse=True,
+    # (B=512, nnz=2048): the double-buffered idx/val tiles alone are
+    # 2*2*512*2048*4 B = 16 MiB
+    sig = WorkloadSignature(n=4096, d=64, nnz=2048, sparse=True,
                             name="selftest-forged")
     topo = Topology(backend="tpu")
     forged = SolverPlan(solver="pallas", route="pallas-replicated",
-                        bucket=16, chunks=1, nnz_multiple=0,
+                        bucket=512, chunks=1, nnz_multiple=0,
                         feature_shard=False)
     _expect(budget.audit_plan(sig, topo, forged),
             rules.VMEM_PLAN_BUDGET, "forged over-budget plan")
-    honest = static_plan(sig, topo, bucket=16)
+    honest = static_plan(sig, topo, bucket=512)
     _expect_clean(budget.audit_plan(sig, topo, honest),
                   f"honestly routed plan ({honest.route})")
 

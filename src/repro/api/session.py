@@ -64,7 +64,25 @@ def margins(v, data) -> jnp.ndarray:
         idx, val = data
         return jnp.sum(jnp.asarray(v)[jnp.asarray(idx)]
                        * jnp.asarray(val), axis=1)
-    return jnp.asarray(data).T @ jnp.asarray(v)
+    return jnp.dot(jnp.asarray(data).T, jnp.asarray(v),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+class _ResidentEpoch:
+    """The jitted resident epoch `fn(alpha, v, epoch, *data)`, called as
+    `(alpha, v, epoch)`.  The data rides in as arguments: arrays a jitted
+    function closes over are embedded in its executable as constants,
+    which at published sizes means hundreds of MB per program."""
+
+    def __init__(self, fn, *data):
+        self._fn = jax.jit(fn)
+        self._data = data
+
+    def __call__(self, a, v, e):
+        return self._fn(a, v, e, *self._data)
+
+    def lower(self, a, v, e):
+        return self._fn.lower(a, v, e, *self._data)
 
 
 def _pad_multiple(spec: EngineConfig, bucket: int) -> int:
@@ -460,17 +478,17 @@ class Session:
                 jit_step=self._jit_step, journal=self._journal,
                 damp=self._damp)
         elif self.sparse:
-            self._epoch_fn = jax.jit(
-                lambda a, v, e: engine.sim_epoch_sparse(
-                    self.obj, self.idx, self.val, self.y, a, v, self.lam,
+            self._epoch_fn = _ResidentEpoch(
+                lambda a, v, e, idx, val, y: engine.sim_epoch_sparse(
+                    self.obj, idx, val, y, a, v, self.lam,
                     self.plan, self.bplan, self.spec, e,
-                    dv_scale_mul=self._damp))
+                    dv_scale_mul=self._damp), self.idx, self.val, self.y)
         else:
-            self._epoch_fn = jax.jit(
-                lambda a, v, e: engine.sim_epoch_dense(
-                    self.obj, self.X, self.y, a, v, self.lam,
+            self._epoch_fn = _ResidentEpoch(
+                lambda a, v, e, X, y: engine.sim_epoch_dense(
+                    self.obj, X, y, a, v, self.lam,
                     self.plan, self.bplan, self.spec, e,
-                    dv_scale_mul=self._damp))
+                    dv_scale_mul=self._damp), self.X, self.y)
 
     def _switch_local_solver(self, kind: str) -> None:
         """Reroute the local solver (the health guard's pallas→xla

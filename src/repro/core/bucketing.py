@@ -7,7 +7,7 @@ n/B bucket ids instead of n example ids.
 
 On TPU the analogous fast memory is VMEM, and the analogous win is
 threefold (see DESIGN.md S2/S6):
-  * the (d_pad x B) data tile for one bucket is streamed HBM->VMEM once
+  * the (B x d_pad) data tile for one bucket is streamed HBM->VMEM once
     and reused for margins, Gram matrix, and the shared-vector update;
   * the per-epoch shuffle is over n/B bucket ids (device-side);
   * processing a bucket through its Gram matrix turns the memory-bound
@@ -27,7 +27,7 @@ from __future__ import annotations
 import dataclasses
 
 # The paper: bucket size = cacheline/8B (8 or 16).  TPU: bucket size is
-# bounded by VMEM (the (d_pad x B) tile + B x B Gram must fit) and should
+# bounded by VMEM (the (B x d_pad) tile + B x B Gram must fit) and should
 # be a multiple of the 8-sublane register shape for the VPU.
 DEFAULT_BUCKET = 16
 # The paper disables bucketing when the model vector (n entries) fits the

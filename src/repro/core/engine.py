@@ -72,18 +72,9 @@ Array = jax.Array
 # all-gather pod reduce, so we assert replication via out_specs instead.
 # Lives here (not launch/glm.py) since the mesh-streamed step below
 # needs it too; launch/glm.py re-imports it.
-try:
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except (ImportError, TypeError):                        # older jax
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 # ---------------------------------------------------------------------------
 # Worker-local data blocks
@@ -813,10 +804,10 @@ def run_epoch(
         return _put_cols(a_c, cols, a_new), v_c
 
     # The chunk loop is unrolled (chunks is a small static count, <= ~8).
-    # A lax.fori_loop here MISCOMPILES under shard_map on current jax:
+    # On jax 0.4.x a lax.fori_loop here miscompiled under shard_map:
     # closed-over values derived from axis_index (the per-lane visit
-    # perm) are treated as loop-invariant-replicated and every lane
-    # silently runs lane 0's visit order — the pre-engine distributed
+    # perm) were treated as loop-invariant-replicated and every lane
+    # silently ran lane 0's visit order — the pre-engine distributed
     # driver had exactly this latent bug.  The sim<->mesh equivalence
     # test (tests/test_engine.py) pins the fixed behaviour.
     for c in range(chunks):

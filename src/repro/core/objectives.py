@@ -153,7 +153,7 @@ def get_objective(name: str) -> Objective:
 def primal_value(obj: Objective, v: Array, X: Array, y: Array,
                  lam: float) -> Array:
     """P(v) for dense X of shape (d, n)."""
-    margins = X.T @ v
+    margins = jnp.dot(X.T, v, precision=jax.lax.Precision.HIGHEST)
     n = y.shape[0]
     return jnp.sum(obj.loss(margins, y)) / n + 0.5 * lam * jnp.sum(v * v)
 

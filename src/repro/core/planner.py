@@ -353,8 +353,8 @@ def feature_shard_default(sig: WorkloadSignature,
     if topo is None:
         topo = Topology(backend="tpu")
     if sig.sparse:
-        d_pad = _round_up(max(sig.d, 8), 8)
-        return d_pad * 4 > topo.v_budget()
+        from repro.kernels.sdca_sparse_bucket import v_bytes
+        return v_bytes(sig.d) > topo.v_budget()
     return sig.d >= 512
 
 

@@ -32,6 +32,11 @@ from .objectives import Objective
 
 Array = jax.Array
 
+#: f32 products on every backend: TPU's default f32 matmul runs one
+#: bf16 pass, which would make the XLA route coarser than the kernels
+#: (CPU computes f32 either way).
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def bucket_solve(obj: Objective, G: Array, m0: Array, a0: Array, y: Array,
                  lam_n: Array, sigma_p: Array) -> Array:
@@ -80,8 +85,8 @@ def dense_local_subepoch(
 
     def step(v, inp):
         Xt, a_b, y_b = inp
-        m0 = Xt.T @ v                     # (B,)
-        G = Xt.T @ Xt                     # (B,B)
+        m0 = jnp.dot(Xt.T, v, precision=_HIGHEST)          # (B,)
+        G = jnp.dot(Xt.T, Xt, precision=_HIGHEST)          # (B,B)
         if model_axis is not None:
             # one fused psum per bucket amortizes the TP collective over B
             # coordinates (vs one per coordinate without bucketing)
@@ -89,13 +94,29 @@ def dense_local_subepoch(
             packed = jax.lax.psum(packed, model_axis)
             m0, G = packed[:, 0], packed[:, 1:]
         deltas = bucket_solve(obj, G, m0, a_b, y_b, lam_n, sigma_p)
-        v = v + (sigma_p / lam_n) * (Xt @ deltas)
+        v = v + (sigma_p / lam_n) * jnp.dot(Xt, deltas, precision=_HIGHEST)
         return v, a_b + deltas
 
     v1, a_new = jax.lax.scan(step, v0, (Xb, ab, yb))
     # CoCoA+: the local replica evolves with the sigma'-scaled updates, but
     # the aggregated global delta is the UNSCALED (1/lam_n) A_k @ dalpha_k.
     return a_new.reshape(-1), (v1 - v0) / sigma_p
+
+
+def row_sq_norms(val: Array) -> Array:
+    """Per-row curvature sum(val**2, axis=-1), added column by column
+    from the left.
+
+    The order is fixed on purpose: XLA vectorizes a row reduction
+    differently for different batch shapes (a vmapped simulator worker
+    vs one mesh lane), and on FMA/AVX-512 hosts the two results then
+    differ in the last bit.  The scan below and both sparse kernel
+    wrappers take q from here, so every path sees the same bits.
+    """
+    sq = val * val
+    return jax.lax.scan(lambda acc, col: (acc + col, None),
+                        jnp.zeros(sq.shape[:-1], sq.dtype),
+                        jnp.moveaxis(sq, -1, 0))[0]
 
 
 def sparse_local_subepoch(
@@ -117,7 +138,7 @@ def sparse_local_subepoch(
     keeps v VMEM-resident and is bitwise-identical to this scan for
     rows obeying the CSR no-duplicate-nonzero invariant (DESIGN.md S11).
     """
-    qii = jnp.sum(val * val, axis=1)                    # (n_local,)
+    qii = row_sq_norms(val)                             # (n_local,)
 
     def step(v, inp):
         ii, vv, y, a, q = inp

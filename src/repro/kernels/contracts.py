@@ -18,7 +18,7 @@ from __future__ import annotations
 __all__ = ["KERNEL_CONTRACTS"]
 
 KERNEL_CONTRACTS: dict[str, dict[str, str]] = {
-    # dense bucket kernel: whole (d_pad, B) tiles + Gram recursion
+    # dense bucket kernel: whole (B, d_pad) tiles + Gram recursion
     "sdca_bucket.sdca_bucket_kernel": {
         "misfit": "repro.kernels.ops:dense_kernel_misfit",
         "vmem_estimate": "repro.kernels.sdca_bucket:vmem_bytes_estimate",

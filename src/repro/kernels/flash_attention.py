@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import compiler_params as _compiler_params
-
 
 NEG_INF = -1e30
 
@@ -123,7 +121,7 @@ def flash_attention_kernel(q, k, v, *, kind: str = "causal",
             pltpu.VMEM((bq, 1), jnp.float32),      # l
             pltpu.VMEM((bq, hd_v), jnp.float32),   # acc
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import sdca
 from repro.core.objectives import Objective
 from . import sdca_bucket, sdca_sparse_bucket, rglru as _rglru
 
@@ -61,12 +62,12 @@ def sparse_slice_width(d: int, model_lanes: int) -> int:
     The ONE formula shared by the kernel driver
     (`sdca_sparse_sharded_subepoch`), the masked XLA twin
     (`engine.sparse_sharded_xla_solver`), and the analytic cost models:
-    ceil(d_pad / M) rounded up to the f32 sublane tile.  Slices are
-    contiguous, disjoint, and cover [0, d) because d_loc * M >= d_pad.
+    ceil(d / M) rounded up to `sdca_sparse_bucket.V_ALIGN` features, so
+    each lane-dense slice fills whole (8, 128) f32 tiles.  Slices are
+    contiguous, disjoint, and cover [0, d) because d_loc * M >= d.
     """
-    d_pad = _round_up(max(d, 8), 8)
     M = max(int(model_lanes), 1)
-    return _round_up(-(-d_pad // M), 8)
+    return sdca_sparse_bucket.v_pad(-(-max(int(d), 1) // M))
 
 
 def sparse_solver_plan(n_local: int, nnz: int, d: int, bucket: int, *,
@@ -94,20 +95,20 @@ def sparse_solver_plan(n_local: int, nnz: int, d: int, bucket: int, *,
             MisfitCode.ALIGNMENT,
             f"(B={bucket}, nnz={nnz}) must both be multiples of 8 "
             f"(f32 sublane tile)")
-    d_pad = _round_up(max(d, 8), 8)
+    ssb = sdca_sparse_bucket
+    d_pad = ssb.v_pad(d)
     M = max(int(model_lanes), 1)
-    if (d_pad * 4 <= sdca_sparse_bucket.V_VMEM_BUDGET_BYTES
-            and sdca_sparse_bucket.vmem_bytes_estimate(bucket, nnz, d_pad)
-            <= sdca_sparse_bucket.TOTAL_VMEM_BUDGET_BYTES):
+    if (ssb.v_bytes(d) <= ssb.V_VMEM_BUDGET_BYTES
+            and ssb.vmem_bytes_estimate(bucket, nnz, d)
+            <= ssb.TOTAL_VMEM_BUDGET_BYTES):
         return "pallas-replicated", None
     if M > 1:
         d_loc = sparse_slice_width(d, M)
-        if (d_loc * 4 <= sdca_sparse_bucket.V_VMEM_BUDGET_BYTES
-                and sdca_sparse_bucket.vmem_bytes_estimate_sharded(
-                    bucket, nnz, d_loc)
-                <= sdca_sparse_bucket.TOTAL_VMEM_BUDGET_BYTES):
+        if (ssb.v_bytes(d_loc) <= ssb.V_VMEM_BUDGET_BYTES
+                and ssb.vmem_bytes_estimate_sharded(bucket, nnz, d_loc)
+                <= ssb.TOTAL_VMEM_BUDGET_BYTES):
             return "pallas-sharded", None
-    if d_pad * 4 > sdca_sparse_bucket.V_VMEM_BUDGET_BYTES:
+    if ssb.v_bytes(d) > ssb.V_VMEM_BUDGET_BYTES:
         text = (f"shared vector of d={d} features exceeds the "
                 f"{sdca_sparse_bucket.V_VMEM_BUDGET_BYTES}-byte "
                 f"resident-v VMEM budget")
@@ -192,12 +193,11 @@ def dense_kernel_misfit(d: int, n_local: int, bucket: int) -> str | None:
         return Misfit(MisfitCode.BUCKET_CAP,
                       f"bucket={bucket} exceeds the kernel's in-bucket "
                       f"recursion cap of B <= {sdca_bucket.MAX_BUCKET}")
-    d_pad = _round_up(max(d, 8), 8)
-    need = sdca_bucket.vmem_bytes_estimate(B_pad, d_pad)
+    need = sdca_bucket.vmem_bytes_estimate(B_pad, d)
     if need > sdca_bucket.TOTAL_VMEM_BUDGET_BYTES:
         return Misfit(MisfitCode.VMEM_TOTAL,
                       f"~{need}-byte VMEM footprint for (B={B_pad}, "
-                      f"d_pad={d_pad}) exceeds the "
+                      f"d_pad={sdca_bucket.d_pad(d)}) exceeds the "
                       f"{sdca_bucket.TOTAL_VMEM_BUDGET_BYTES}-byte budget")
     return None
 
@@ -265,6 +265,14 @@ def _check_csr_invariant(idx, val, source: str) -> None:
     _csr_mark_checked(idx, val)
 
 
+def _lane_dense(v, d_pad: int):
+    """(d,) v zero-padded to the sparse kernels' lane-dense
+    (rows, 128) f32 layout of at least d_pad features."""
+    d_pad = sdca_sparse_bucket.v_pad(d_pad)
+    return jnp.zeros((d_pad,), jnp.float32).at[:v.shape[0]].set(
+        v.astype(jnp.float32)).reshape(-1, sdca_sparse_bucket.LANES)
+
+
 def sdca_bucket_subepoch(obj: Objective, Xl, yl, al, v0, lam_n, sig, *,
                          bucket: int, interpret: bool | None = None,
                          source: str = "ad-hoc arrays"):
@@ -280,12 +288,12 @@ def sdca_bucket_subepoch(obj: Objective, Xl, yl, al, v0, lam_n, sig, *,
     d, n_local = Xl.shape
     B = bucket
     nb = n_local // B
-    d_pad = _round_up(max(d, 8), 8)
+    d_pad = sdca_bucket.d_pad(d)
     B_pad = _round_up(max(B, 8), 8)
 
-    xb = Xl.reshape(d, nb, B).transpose(1, 0, 2)
+    xb = Xl.reshape(d, nb, B).transpose(1, 2, 0)      # (nb, B, d)
     if d_pad != d or B_pad != B:
-        xb = jnp.pad(xb, ((0, 0), (0, d_pad - d), (0, B_pad - B)))
+        xb = jnp.pad(xb, ((0, 0), (0, B_pad - B), (0, d_pad - d)))
     yb = yl.reshape(nb, B)
     ab = al.reshape(nb, B)
     if B_pad != B:
@@ -299,7 +307,7 @@ def sdca_bucket_subepoch(obj: Objective, Xl, yl, al, v0, lam_n, sig, *,
         yb = jnp.pad(yb, ((0, 0), (0, B_pad - B)))
         ab = jnp.pad(ab, ((0, 0), (0, B_pad - B)))
 
-    v0p = jnp.zeros((d_pad, 1), jnp.float32).at[:d, 0].set(
+    v0p = jnp.zeros((1, d_pad), jnp.float32).at[0, :d].set(
         v0.astype(jnp.float32))
     scal = jnp.stack([jnp.float32(lam_n), jnp.float32(sig)])
 
@@ -307,7 +315,7 @@ def sdca_bucket_subepoch(obj: Objective, Xl, yl, al, v0, lam_n, sig, *,
         obj, xb, yb, ab, v0p, scal, interpret, source)
 
     a_out = a_new[:, :B].reshape(-1)
-    dv = (v_fin[:d, 0] - v0.astype(jnp.float32)) / jnp.float32(sig)
+    dv = (v_fin[0, :d] - v0.astype(jnp.float32)) / jnp.float32(sig)
     return a_out.astype(al.dtype), dv.astype(v0.dtype)
 
 
@@ -340,26 +348,25 @@ def sdca_sparse_bucket_subepoch(obj: Objective, idx, val, yl, al, v0,
             f"bucket={B} must divide the {source} chunk's row count "
             f"{n_local} (the engine hands the kernel whole buckets)")
     d = v0.shape[0]
-    d_pad = _round_up(max(d, 8), 8)
 
     idxb = idx.reshape(n_local // B, B, nnz)
     valb = val.reshape(n_local // B, B, nnz)
     yb = yl.reshape(n_local // B, B)
     ab = al.reshape(n_local // B, B)
-    # per-row curvature at FULL chunk shape — the scan's exact
-    # expression; the kernel must not recompute it per tile (see
+    # per-row curvature from the scan's own `sdca.row_sq_norms`; the
+    # kernel must not recompute it per tile (see
     # sdca_sparse_bucket._kernel on why this is bitwise-load-bearing)
-    valf = val.astype(jnp.float32)
-    qb = jnp.sum(valf * valf, axis=1).reshape(n_local // B, B)
-    v0p = jnp.zeros((d_pad, 1), jnp.float32).at[:d, 0].set(
-        v0.astype(jnp.float32))
+    qb = sdca.row_sq_norms(val.astype(jnp.float32)).reshape(
+        n_local // B, B)
     scal = jnp.stack([jnp.float32(lam_n), jnp.float32(sig)])
 
     a_new, v_fin = sdca_sparse_bucket.sdca_sparse_bucket_kernel(
-        obj, idxb, valb, yb, ab, qb, v0p, scal, interpret, source)
+        obj, idxb, valb, yb, ab, qb, _lane_dense(v0, d), scal, interpret,
+        source)
 
     a_out = a_new.reshape(-1)
-    dv = (v_fin[:d, 0] - v0.astype(jnp.float32)) / jnp.float32(sig)
+    dv = (v_fin.reshape(-1)[:d] - v0.astype(jnp.float32)) \
+        / jnp.float32(sig)
     return a_out.astype(al.dtype), dv.astype(v0.dtype)
 
 
@@ -402,11 +409,10 @@ def sdca_sparse_sharded_subepoch(obj: Objective, idx, val, yl, al, v0,
     d = v0.shape[0]
     M = max(int(model_lanes), 1)
     d_loc = sparse_slice_width(d, M)
-    d_pad = d_loc * M
     nb = n_local // B
 
     if model_axis is not None:
-        # audit: collective-ok lane id seeds the lo carry (threaded below)
+        # audit: collective-ok lane id seeds lo (scanned as xs below)
         lane_ix = jax.lax.axis_index(model_axis).astype(jnp.int32)
     else:
         lane_ix = jnp.int32(0 if lane is None else lane)
@@ -419,20 +425,20 @@ def sdca_sparse_sharded_subepoch(obj: Objective, idx, val, yl, al, v0,
     # per-row curvature at FULL chunk shape — bitwise-load-bearing,
     # exactly as in the replicated wrapper (and replicated over lanes:
     # every lane sees the same q bits the scan uses)
-    valf = val.astype(jnp.float32)
-    qb = jnp.sum(valf * valf, axis=1).reshape(nb, B)
-    v_pad = jnp.zeros((d_pad, 1), jnp.float32).at[:d, 0].set(
-        v0.astype(jnp.float32))
-    v_loc0 = jax.lax.dynamic_slice(v_pad, (lo0, 0), (d_loc, 1))
+    qb = sdca.row_sq_norms(val.astype(jnp.float32)).reshape(nb, B)
+    v_flat = _lane_dense(v0, d_loc * M).reshape(-1)
+    v_loc0 = jax.lax.dynamic_slice(v_flat, (lo0,), (d_loc,)).reshape(
+        d_loc // sdca_sparse_bucket.LANES, sdca_sparse_bucket.LANES)
     scal = jnp.stack([jnp.float32(lam_n), jnp.float32(sig)])
 
-    # lo rides in the scan carry: shard_map treats closed-over
-    # axis_index-derived values inside loops as loop-invariant-
-    # replicated on current jax (see engine.run_epoch's unrolled chunk
-    # loop) — carrying it through keeps every lane on its own slice.
-    def _step(carry, tile):
-        v_loc, lo = carry
-        idx_t, val_t, y_t, a_t, q_t = tile
+    # lo rides in the scanned xs, one copy per bucket: a value the loop
+    # closes over — or a carry it only forwards, which jax hoists into
+    # a closure — is an axis_index-derived loop invariant, the
+    # JAX-LOOP-CLOSURE hazard class (see engine.run_epoch's unrolled
+    # chunk loop).  Per-iteration inputs keep every lane on its own
+    # slice by construction.
+    def _step(v_loc, tile):
+        idx_t, val_t, y_t, a_t, q_t, lo = tile
         w_loc = sdca_sparse_bucket.sdca_sparse_gather_bucket(
             idx_t, v_loc, lo, interpret, source)
         if model_axis is not None and M > 1:
@@ -445,14 +451,15 @@ def sdca_sparse_sharded_subepoch(obj: Objective, idx, val, yl, al, v0,
         a_new_t, v_loc = sdca_sparse_bucket.sdca_sparse_sharded_bucket(
             obj, idx_t, val_t, y_t, a_t, q_t, w, v_loc, scal, lo,
             interpret, source)
-        return (v_loc, lo), a_new_t
+        return v_loc, a_new_t
 
-    (v_fin, _), a_new = jax.lax.scan(
-        _step, (v_loc0, lo0), (idxb, valb, yb, ab, qb))
+    v_fin, a_new = jax.lax.scan(
+        _step, v_loc0,
+        (idxb, valb, yb, ab, qb, jnp.broadcast_to(lo0, (nb,))))
 
-    dv_loc = (v_fin[:, 0] - v_loc0[:, 0]) / jnp.float32(sig)
+    dv_loc = (v_fin - v_loc0).reshape(-1) / jnp.float32(sig)
     dv = jax.lax.dynamic_update_slice(
-        jnp.zeros((d_pad,), jnp.float32), dv_loc, (lo0,))[:d]
+        jnp.zeros((d_loc * M,), jnp.float32), dv_loc, (lo0,))[:d]
     return a_new.reshape(-1).astype(al.dtype), dv.astype(v0.dtype)
 
 

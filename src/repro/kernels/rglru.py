@@ -22,8 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .pallas_compat import compiler_params as _compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 _C = 8.0
@@ -92,7 +91,7 @@ def rglru_kernel(x, a_log, gate_a, gate_x, h0, *, block_t: int = 128,
             jax.ShapeDtypeStruct((T, D), x.dtype),
             jax.ShapeDtypeStruct((1, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, gate_a, gate_x, a_log.reshape(1, D), h0.reshape(1, D))

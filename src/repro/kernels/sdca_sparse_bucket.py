@@ -6,33 +6,42 @@ formulation (`core.sdca.sparse_local_subepoch`) is a per-coordinate
 pays a v-sized gather + scatter through HBM.  Here the paper's
 cache-resident shared vector maps onto VMEM:
 
-  * v (d_pad x 1, f32) is pinned in VMEM for the whole sub-epoch via
-    input/output aliasing + a constant index map — idx/val tiles are
-    the ONLY per-bucket HBM traffic;
+  * v is pinned in VMEM for the whole sub-epoch, laid out LANE-DENSE as
+    (d_pad / 128, 128) f32 — feature p lives at row p >> 7, lane
+    p & 127, so d features take d_pad * 4 bytes of VMEM (a (d, 1)
+    column would be padded to 128 lanes by the (8, 128) tiling).  It is
+    copied in from HBM once at the first bucket and written back once
+    after the last; idx/val tiles are the ONLY per-bucket HBM traffic;
   * each grid step streams one (B, nnz) idx/val tile pair HBM->VMEM —
     exactly the mmap-aligned layout `data/cache.py` stores, so cached
-    tiles DMA straight in;
+    tiles DMA straight in.  The idx tile also lands in SMEM: feature
+    ids are scalar addresses, and only SMEM feeds the scalar unit;
   * the touched feature rows are gathered once per bucket into a
-    bucket-local working set W (B, nnz) at bucket entry;
-  * the in-bucket recursion runs on VMEM-resident state only: O(B*nnz)
-    gather/scatter scalars + an O(B) delta recursion whose cross-
-    coordinate margin corrections are vectorized (B, nnz) x nnz
-    compare/accumulate VPU work (no Gram matrix: a sparse-sparse Gram
-    needs the same index matching but materializes B^2 values that are
-    almost all zero);
+    bucket-local working set W (B, nnz) (VMEM scratch) at bucket entry;
+  * the in-bucket recursion runs on VMEM-resident state only: per
+    coordinate one margin, one scalar dual update, one update row u,
+    and an nnz-step loop that adds u's entries into every working-set
+    entry that aliases the same feature (a (B, nnz) compare/select per
+    step — later rows see the v the scan would show them);
   * v is written back once per bucket (one scatter pass in visiting
     order) instead of once per coordinate.
+
+Mosaic has no dynamic slicing of VALUES, so every per-coordinate read
+goes through a ref: rows via `pl.ds`, scalars from SMEM, a lane of a
+row by an iota-mask select (`_lane`), and writes into a row by the
+same mask (`jnp.where`).
 
 Bit-equivalence contract: for the same visiting order the kernel is
 BITWISE-identical to `sparse_local_subepoch` (pinned by interpret-mode
 tests on CPU).  Two things make that hold and must not be "simplified"
 away:
 
-  * every floating-point add applies the exact values the scan adds —
-    the per-coordinate update row u = (sigma' * delta / lam_n) * val
-    is computed ONCE (same association as the scan) and only ever
-    ADDED elementwise; folding the multiply into the adds lets XLA
-    fuse them into FMAs and drifts low bits;
+  * every floating-point add applies the exact values the scan adds,
+    in the scan's order — the per-coordinate update row
+    u = (sigma' * delta / lam_n) * val is computed ONCE (same
+    association as the scan) and only ever ADDED elementwise; folding
+    the multiply into the adds lets XLA fuse them into FMAs and drifts
+    low bits;
   * rows must satisfy the CSR invariant: no duplicate feature id with
     a nonzero value within a row (padding with idx=0/val=0 is fine —
     zero-valued duplicates add exact zeros on both paths).  Real
@@ -42,14 +51,15 @@ away:
 Grid is 1-D over buckets with "arbitrary" dimension semantics: buckets
 are processed IN ORDER (sequential SDCA semantics).
 
-Alignment: B and nnz must be multiples of 8 (f32 sublane tile), d_pad
-a multiple of 8, and v must fit the VMEM budget below.  Scalars
-(lam*n, sigma') ride in SMEM.
+Alignment: B and nnz must be multiples of 8 (f32 sublane tile); v is
+padded to a multiple of `V_ALIGN` features and must fit the VMEM budget
+below.  Scalars (lam*n, sigma') ride in SMEM.
 
-Feature-sharded variant (DESIGN.md S12): when d_pad rows of v cannot
-fit one core's VMEM budget, each `model`-axis lane owns ONE contiguous
-d_loc = roundup(ceil(d_pad / M), 8) slice of v instead.  The sub-epoch
-becomes a per-bucket pair of kernels around one model-axis exchange:
+Feature-sharded variant (DESIGN.md S12): when v cannot fit one core's
+VMEM budget, each `model`-axis lane owns ONE contiguous
+d_loc = roundup(ceil(d / M), V_ALIGN) slice of v instead.  The
+sub-epoch becomes a per-bucket pair of kernels around one model-axis
+exchange:
 
   * `_gather_slice_kernel`: gather the bucket's touched rows that fall
     in this lane's slice (out-of-slice entries read as exact 0.0);
@@ -61,8 +71,8 @@ becomes a per-bucket pair of kernels around one model-axis exchange:
     the summation order and breaks the bitwise-vs-scan contract;
   * `_sharded_kernel`: run the SAME in-bucket recursion
     (`_bucket_recursion`, shared code) on the assembled W — every lane
-    redundantly, O(B*nnz) VPU work — then scatter only the owned
-    entries back into the slice, in visiting order.
+    redundantly — then scatter only the owned entries back into the
+    slice, in visiting order.
 
 One exchange (M*B*nnz f32) per bucket is the whole model-axis wire
 cost, amortized over B coordinates — the bucket optimization's payoff
@@ -78,164 +88,213 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.objectives import Objective
-from .pallas_compat import compiler_params as _compiler_params
 
 Array = jax.Array
 
-#: VMEM bytes the resident shared vector may occupy (~half a v5e core's
-#: 16 MB, leaving room for double-buffered idx/val tiles + the working
-#: set).  d above this must use local_solver="xla" (HBM-resident v) or
-#: shard features.
+#: Lanes per v row: feature p lives at (p >> 7, p & 127).
+LANES = 128
+
+#: v is padded to a multiple of this many features, so its
+#: (d_pad / 128, 128) layout fills whole (8, 128) f32 tiles.
+V_ALIGN = 8 * LANES
+
+#: VMEM bytes the resident shared vector may occupy.  A v5e TensorCore
+#: has 128 MiB of VMEM, but Mosaic grants a kernel a 16 MiB scoped
+#: window by default; v gets half of it, leaving room for the
+#: double-buffered idx/val tiles and the working set.  d above this
+#: must use local_solver="xla" (HBM-resident v) or shard features.
 V_VMEM_BUDGET_BYTES = 8 * 2 ** 20
 
-#: Total VMEM the kernel's buffers may claim together (a v5e core has
-#: ~16 MiB; leave headroom for Mosaic spills/scratch).  On wide tiles
-#: the per-coordinate (B, nnz, nnz) match tensor dominates and must be
-#: budgeted up front — exceeding VMEM inside Mosaic is an opaque OOM,
-#: not a Python error.
+#: Total VMEM the kernel's buffers may claim together: the default
+#: 16 MiB scoped window less headroom for Mosaic's own scratch.
+#: Exceeding the window inside Mosaic is a compile-time OOM, not a
+#: Python error, so the footprint is budgeted up front.
 TOTAL_VMEM_BUDGET_BYTES = 14 * 2 ** 20
 
 
-def vmem_bytes_estimate(B: int, nnz: int, d_pad: int) -> int:
-    """Upper-bound VMEM footprint of one grid step.
+def _round_up(x: int, m: int) -> int:
+    return (x + m - 1) // m * m
 
-    Counts the resident v, the double-buffered idx(int32)/val(f32)
-    tiles, the W/U/vals/corr working sets, and the per-coordinate
-    (B, nnz, nnz) match tensors — the bool compare mask (1 B/elt) AND
-    the f32 `jnp.where` product (4 B/elt) are live together in the
-    recursion body.  Shared with `ops.sparse_kernel_misfit` so the
-    "auto" path can pre-check static shapes and fall back instead of
-    raising.
+
+def v_pad(d: int) -> int:
+    """Features the lane-dense v of d features is padded to."""
+    return _round_up(max(int(d), 1), V_ALIGN)
+
+
+def v_bytes(d: int) -> int:
+    """VMEM bytes of a resident lane-dense v (or v slice) of d features."""
+    return v_pad(d) * 4
+
+
+def _tile_bytes(rows: int, cols: int) -> int:
+    """VMEM bytes of one (rows, cols) 32-bit buffer in (8, 128) tiles."""
+    return _round_up(max(rows, 1), 8) * _round_up(max(cols, 1), LANES) * 4
+
+
+def vmem_bytes_estimate(B: int, nnz: int, d: int) -> int:
+    """VMEM footprint of the replicated kernel, as the compiler lays it
+    out (pinned against a v5e compile in tests/test_tpu_compile.py).
+
+    Counts the one resident copy of v, the double-buffered idx(int32)
+    and val(f32) tiles, the double-buffered (1, B) y/alpha/q/alpha-out
+    rows, and the W/U working-set scratch.  Shared with
+    `ops.sparse_solver_plan` so the "auto" path can pre-check static
+    shapes and fall back instead of raising.
     """
-    v = d_pad * 4
-    tiles = 2 * B * nnz * (4 + 4)
-    work = 4 * B * nnz * 4
-    match = B * nnz * nnz * (4 + 1)
-    return v + tiles + work + match
+    tiles = 2 * 2 * _tile_bytes(B, nnz)
+    rows = 4 * 2 * _tile_bytes(1, B)
+    work = 2 * _tile_bytes(B, nnz)
+    return v_bytes(d) + tiles + rows + work
 
 
 def vmem_bytes_estimate_sharded(B: int, nnz: int, d_loc: int) -> int:
-    """Upper-bound VMEM footprint of ONE bucket of the sharded pair.
+    """VMEM footprint of ONE bucket of the sharded pair.
 
     The update kernel dominates: the resident v SLICE, one (not
     double-buffered — one bucket per call) idx/val tile pair, the
-    exchanged working set W, the U/vals/corr working sets, and the same
-    (B, nnz, nnz) match tensors as the replicated kernel.  Shared with
-    `ops.sparse_solver_plan` so the dispatcher can pre-check the
-    sharded route on static shapes.
+    exchanged working set W, the y/alpha/q/alpha-out rows and the U
+    scratch.  Shared with `ops.sparse_solver_plan` so the dispatcher
+    can pre-check the sharded route on static shapes.
     """
-    v = d_loc * 4
-    tiles = B * nnz * (4 + 4)
-    wexch = B * nnz * 4
-    work = 4 * B * nnz * 4
-    match = B * nnz * nnz * (4 + 1)
-    return v + tiles + wexch + work + match
+    tiles = 2 * _tile_bytes(B, nnz)
+    wexch = _tile_bytes(B, nnz)
+    rows = 4 * _tile_bytes(1, B)
+    work = _tile_bytes(B, nnz)
+    return v_bytes(d_loc) + tiles + wexch + rows + work
 
 
-def _gather_rows(idx, read):
-    """W[i, k] = read(idx[i, k]) via a scalar loop over the tile.
+def _lane(row, k):
+    """row[0, k] of a (1, n) value as a (1, 1) value: an iota-mask
+    select summed over lanes (exactly row[0, k] — every other term is
+    +0.0)."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(ids == k, row, 0.0), axis=1, keepdims=True)
+
+
+def _read_v(v_ref, p, lane_ok=True):
+    """v[p] of a lane-dense (rows, 128) v ref as a (1, 1) value, or
+    exact 0.0 where `lane_ok` is False."""
+    row = v_ref[pl.ds(p >> 7, 1), :]
+    return _lane(row, jnp.where(lane_ok, p & (LANES - 1), -1))
+
+
+def _add_v(v_ref, p, u, lane_ok=True):
+    """v[p] += u (u a (1, 1) value); a no-op where `lane_ok` is False —
+    the row is stored back bit-for-bit."""
+    r = pl.ds(p >> 7, 1)
+    row = v_ref[r, :]
+    ids = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    hit = ids == jnp.where(lane_ok, p & (LANES - 1), -1)
+    v_ref[r, :] = jnp.where(hit, row + u, row)
+
+
+def _gather_rows(idx_s, w_ref, read):
+    """w_ref[i, k] = read(idx[i, k]) for the whole (B, nnz) tile.
 
     Shared by the replicated kernel (read = v lookup) and the sharded
     gather kernel (read = masked slice lookup): the loop structure must
     stay identical so both produce the same W bits for owned entries.
     """
-    B, nnz = idx.shape
+    B, nnz = w_ref.shape
+    ids = jax.lax.broadcasted_iota(jnp.int32, (1, nnz), 1)
 
-    def gather(t, W):
-        i = t // nnz
-        k = t - i * nnz
-        p = jax.lax.dynamic_slice(idx, (i, k), (1, 1))[0, 0]
-        w = read(p)
-        return jax.lax.dynamic_update_slice(W, w[None, None], (i, k))
+    def row(i, carry):
+        def entry(k, w):
+            return jnp.where(ids == k, read(idx_s[i, k]), w)
 
-    return jax.lax.fori_loop(0, B * nnz, gather,
-                             jnp.zeros((B, nnz), jnp.float32))
+        w_ref[pl.ds(i, 1), :] = jax.lax.fori_loop(
+            0, nnz, entry, jnp.zeros((1, nnz), jnp.float32))
+        return carry
+
+    jax.lax.fori_loop(0, B, row, 0)
 
 
-def _bucket_recursion(obj: Objective, idx, vals, y, a0, qrow, lam_n, sig,
-                      W):
-    """The in-bucket delta recursion on a gathered working set W.
+def _bucket_recursion(obj: Objective, idx_s, idx, val_ref, y, a0, qrow,
+                      lam_n, sig, w_ref, u_ref):
+    """The in-bucket delta recursion on the gathered working set in
+    `w_ref`; writes the per-coordinate update rows into `u_ref` and
+    returns the (1, B) alpha deltas.
 
-    -> (U, deltas): the per-coordinate update rows (computed ONCE each,
-    see the module docstring's bitwise contract) and the alpha deltas.
     Shared VERBATIM by the replicated and sharded kernels — the sharded
     path's bitwise claim is exactly "same W bits in, same U bits out".
-    After coordinate i, later rows' working-set entries that alias a
-    feature i touched receive the SAME u-element the scan scatter-adds
-    into v, so margins stay bit-equal.
+    After coordinate i, every working-set entry that aliases a feature
+    i touched receives the SAME u-element the scan scatter-adds into v,
+    in the scan's order, so later margins stay bit-equal.
     """
-    B, nnz = idx.shape
+    B, nnz = w_ref.shape
+    lanes_b = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
 
-    def body(i, carry):
-        W, U, deltas = carry
-        vi = jax.lax.dynamic_slice_in_dim(vals, i, 1, 0)[0]    # (nnz,)
-        ii = jax.lax.dynamic_slice_in_dim(idx, i, 1, 0)[0]
-        wi = jax.lax.dynamic_slice_in_dim(W, i, 1, 0)[0]
-        m = jnp.sum(wi * vi)
-        q = jax.lax.dynamic_index_in_dim(qrow, i, keepdims=False)
-        yi = jax.lax.dynamic_index_in_dim(y, i, keepdims=False)
-        ai = jax.lax.dynamic_index_in_dim(a0, i, keepdims=False)
-        d = obj.delta(m, ai, yi, sig * q / lam_n)
+    def body(i, deltas):
+        vi = val_ref[pl.ds(i, 1), :].astype(jnp.float32)      # (1, nnz)
+        m = jnp.sum(w_ref[pl.ds(i, 1), :] * vi, axis=1, keepdims=True)
+        d = obj.delta(m, _lane(a0, i), _lane(y, i),
+                      sig * _lane(qrow, i) / lam_n)
         # the scan's update row, computed once with its association
         u = (sig * d / lam_n) * vi
-        match = idx[:, :, None] == ii[None, None, :]   # (B, nnz, nnz)
-        corr = jnp.sum(jnp.where(match, u[None, None, :], 0.0), axis=-1)
-        hit = jnp.any(match, axis=-1)
-        W = jnp.where(hit, W + corr, W)
-        U = jax.lax.dynamic_update_slice_in_dim(U, u[None], i, axis=0)
-        deltas = jax.lax.dynamic_update_index_in_dim(deltas, d, i, axis=0)
-        return W, U, deltas
+        u_ref[pl.ds(i, 1), :] = u
 
-    _, U, deltas = jax.lax.fori_loop(
-        0, B, body, (W, jnp.zeros((B, nnz), jnp.float32),
-                     jnp.zeros((B,), jnp.float32)))
-    return U, deltas
+        def alias(k, carry):
+            W = w_ref[...]
+            w_ref[...] = jnp.where(idx == idx_s[i, k], W + _lane(u, k), W)
+            return carry
+
+        jax.lax.fori_loop(0, nnz, alias, 0)
+        return jnp.where(lanes_b == i, d, deltas)
+
+    return jax.lax.fori_loop(0, B, body, jnp.zeros((1, B), jnp.float32))
 
 
-def _kernel(obj: Objective, idx_ref, val_ref, y_ref, a_ref, q_ref,
-            scal_ref, v_ref, aout_ref, vout_ref):
+def _scatter_rows(idx_s, u_ref, add):
+    """add(idx[i, k], U[i, k]) over the tile, rows in visiting order so
+    shared features accumulate in the scan's sequence."""
+    B, nnz = u_ref.shape
+
+    def row(i, carry):
+        u = u_ref[pl.ds(i, 1), :]
+
+        def entry(k, c):
+            add(idx_s[i, k], _lane(u, k))
+            return c
+
+        return jax.lax.fori_loop(0, nnz, entry, carry)
+
+    jax.lax.fori_loop(0, B, row, 0)
+
+
+def _kernel(obj: Objective, idx_s, idx_ref, val_ref, y_ref, a_ref, q_ref,
+            scal_ref, v_hbm, aout_ref, v_ref, w_ref, u_ref):
     """Body for one bucket (one grid step) — replicated v."""
-    first = pl.program_id(0) == 0
-
-    # v lives in the aliased output block; seed it from the input once.
-    @pl.when(first)
+    # v lives in the aliased output block; copy it in from HBM once
+    @pl.when(pl.program_id(0) == 0)
     def _():
-        vout_ref[...] = v_ref[...]
+        pltpu.sync_copy(v_hbm, v_ref)
 
-    idx = idx_ref[0]                            # (B, nnz) int32
-    vals = val_ref[0].astype(jnp.float32)       # (B, nnz)
-    y = y_ref[0].astype(jnp.float32)            # (B,)
-    a0 = a_ref[0].astype(jnp.float32)           # (B,)
-    # per-row curvature ||x_i||^2, PRECOMPUTED by the wrapper with the
-    # scan's exact whole-array row-sum: recomputing it per tile inside
-    # the kernel lets XLA vectorize the reduction differently and
-    # drifts q by 1 ulp on some rows, which the bisection amplifies —
-    # the bitwise contract dies there (found the hard way).
-    qrow = q_ref[0].astype(jnp.float32)         # (B,)
+    # per-row curvature ||x_i||^2 arrives PRECOMPUTED by the wrapper
+    # with the scan's own `sdca.row_sq_norms`: recomputing it per
+    # tile inside the kernel lets XLA vectorize the reduction
+    # differently and drifts q by 1 ulp on some rows, which the
+    # bisection amplifies — the bitwise contract dies there.
+    a0 = a_ref[...].astype(jnp.float32)                     # (1, B)
     lam_n = scal_ref[0]
     sig = scal_ref[1]
-    B, nnz = idx.shape
 
     # 1. bucket entry: gather the touched rows into the working set
     #    W[i, k] = v[idx[i, k]]  (the only reads of v this bucket)
-    W = _gather_rows(idx, lambda p: vout_ref[p, 0])
+    _gather_rows(idx_s, w_ref, lambda p: _read_v(v_ref, p))
 
     # 2. in-bucket recursion entirely on VMEM-resident state
-    U, deltas = _bucket_recursion(obj, idx, vals, y, a0, qrow, lam_n,
-                                  sig, W)
+    deltas = _bucket_recursion(
+        obj, idx_s, idx_ref[...], val_ref, y_ref[...].astype(jnp.float32),
+        a0, q_ref[...].astype(jnp.float32), lam_n, sig, w_ref, u_ref)
 
-    # 3. scatter back into v ONCE per bucket, rows in visiting order so
-    #    shared features accumulate in the scan's sequence
-    def scatter(t, carry):
-        i = t // nnz
-        k = t - i * nnz
-        p = jax.lax.dynamic_slice(idx, (i, k), (1, 1))[0, 0]
-        u = jax.lax.dynamic_slice(U, (i, k), (1, 1))[0, 0]
-        vout_ref[p, 0] = vout_ref[p, 0] + u
-        return carry
+    # 3. scatter back into v ONCE per bucket
+    _scatter_rows(idx_s, u_ref, lambda p, u: _add_v(v_ref, p, u))
+    aout_ref[...] = (a0 + deltas).astype(aout_ref.dtype)
 
-    jax.lax.fori_loop(0, B * nnz, scatter, 0)
-    aout_ref[0] = (a0 + deltas).astype(aout_ref.dtype)
+
+def _row_spec(B: int) -> pl.BlockSpec:
+    """(nb, 1, B) per-bucket vectors, one (1, B) row per grid step."""
+    return pl.BlockSpec((None, 1, B), lambda i: (i, 0, 0))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 8, 9))
@@ -250,14 +309,14 @@ def sdca_sparse_bucket_kernel(obj: Objective, idx: Array, val: Array,
     idx/val: (nb, B, nnz) bucket tiles in visiting order (the tile
     cache's on-disk layout); yb, ab, qb: (nb, B) — qb is the per-row
     curvature sum(val^2) precomputed at full-chunk shape (see _kernel);
-    v0: (d_pad, 1) f32; scal: (2,) f32 = [lam*n, sigma'].  Returns
-    (a_new (nb, B), v_final (d_pad, 1)); v_final includes the
-    sigma'-scaled local evolution (callers unscale the global delta).
-    `source` names where the tiles came from so alignment errors point
-    at the right fix.
+    v0: (d_pad / 128, 128) f32 lane-dense v; scal: (2,) f32 =
+    [lam*n, sigma'].  Returns (a_new (nb, B), v_final like v0); v_final
+    includes the sigma'-scaled local evolution (callers unscale the
+    global delta).  `source` names where the tiles came from so
+    alignment errors point at the right fix.
     """
     nb, B, nnz = idx.shape
-    d_pad = v0.shape[0]
+    d_pad = v0.shape[0] * v0.shape[1]
     if B % 8 or nnz % 8:
         raise ValueError(
             f"sparse bucket tiles from {source} have (B={B}, nnz={nnz}); "
@@ -267,14 +326,15 @@ def sdca_sparse_bucket_kernel(obj: Objective, idx: Array, val: Array,
             f"nnz_multiple=8) for cached tiles, or zero-pad ad-hoc "
             f"idx/val arrays with idx=0/val=0 columns (and pick a "
             f"bucket size that is a multiple of 8).")
-    if d_pad % 8:
+    if v0.shape[1] != LANES or d_pad % V_ALIGN:
         raise ValueError(
-            f"v tile from {source} has d_pad={d_pad}, which must be a "
-            f"multiple of 8; pad the shared vector with zero rows "
+            f"v tile from {source} has shape {v0.shape}; the kernel "
+            f"needs a lane-dense (rows, {LANES}) v with rows a multiple "
+            f"of 8 — pad the shared vector with zero features "
             f"(ops.sdca_sparse_bucket_subepoch does this automatically)")
-    if d_pad * 4 > V_VMEM_BUDGET_BYTES:
+    if v_bytes(d_pad) > V_VMEM_BUDGET_BYTES:
         raise ValueError(
-            f"shared vector of d_pad={d_pad} features ({d_pad * 4} "
+            f"shared vector of d_pad={d_pad} features ({v_bytes(d_pad)} "
             f"bytes) exceeds the sparse kernel's VMEM budget "
             f"({V_VMEM_BUDGET_BYTES} bytes, ~{V_VMEM_BUDGET_BYTES // 4} "
             f"features).  Use local_solver='xla' (HBM-resident v) for "
@@ -283,41 +343,39 @@ def sdca_sparse_bucket_kernel(obj: Objective, idx: Array, val: Array,
     if need > TOTAL_VMEM_BUDGET_BYTES:
         raise ValueError(
             f"sparse bucket tiles from {source} with (B={B}, nnz={nnz}, "
-            f"d_pad={d_pad}) need ~{need} bytes of VMEM — the per-"
-            f"coordinate (B, nnz, nnz) match tensor alone is "
-            f"{B * nnz * nnz * 5} bytes (bool mask + f32 product) — "
-            f"over the kernel's "
-            f"{TOTAL_VMEM_BUDGET_BYTES}-byte total budget.  Use "
+            f"d_pad={d_pad}) need ~{need} bytes of VMEM (resident v + "
+            f"double-buffered (B, nnz) tiles + working set), over the "
+            f"kernel's {TOTAL_VMEM_BUDGET_BYTES}-byte total budget.  Use "
             f"local_solver='xla' (HBM-resident v) for this workload, or "
             f"shrink bucket/nnz so the tiles fit.")
 
-    grid = (nb,)
+    tile = pl.BlockSpec((None, B, nnz), lambda i: (i, 0, 0))
     a_new, v_fin = pl.pallas_call(
         functools.partial(_kernel, obj),
-        grid=grid,
+        grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, B, nnz), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, B, nnz), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
+            pl.BlockSpec((None, B, nnz), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            tile, tile, _row_spec(B), _row_spec(B), _row_spec(B),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),
+            _row_spec(B),
+            pl.BlockSpec(v0.shape, lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, B), ab.dtype),
-            jax.ShapeDtypeStruct((d_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, B), ab.dtype),
+            jax.ShapeDtypeStruct(v0.shape, jnp.float32),
         ],
-        input_output_aliases={6: 1},   # v0 buffer reused as v_final
-        compiler_params=_compiler_params(
+        scratch_shapes=[pltpu.VMEM((B, nnz), jnp.float32),
+                        pltpu.VMEM((B, nnz), jnp.float32)],
+        input_output_aliases={7: 1},   # v0 buffer reused as v_final
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(idx, val, yb, ab, qb, scal, v0)
-    return a_new, v_fin
+    )(idx, idx, val, yb[:, None], ab[:, None], qb[:, None], scal, v0)
+    return a_new[:, 0], v_fin
 
 
 # ---------------------------------------------------------------------------
@@ -328,95 +386,85 @@ def sdca_sparse_bucket_kernel(obj: Objective, idx: Array, val: Array,
 # ---------------------------------------------------------------------------
 
 
-def _gather_slice_kernel(idx_ref, lo_ref, v_ref, w_ref):
+def _owned(p, lo, d_loc):
+    """(slice-local feature id, owned?) for global feature p."""
+    q = p - lo
+    ok = jnp.logical_and(q >= 0, q < d_loc)
+    return jnp.where(ok, q, 0), ok
+
+
+def _gather_slice_kernel(idx_s, lo_ref, v_ref, w_ref):
     """W_loc[i, k] = v_slice[idx[i, k] - lo] when owned, else exact 0.0.
 
     The masked read keeps the owned entries' bits identical to the
     replicated kernel's gather; unowned entries are filled by the
     owning lane after the exchange.
     """
-    idx = idx_ref[...]                          # (B, nnz) int32
     lo = lo_ref[0]
-    d_loc = v_ref.shape[0]
-
-    def read(p):
-        q = p - lo
-        ok = jnp.logical_and(q >= 0, q < d_loc)
-        qc = jnp.where(ok, q, 0)
-        return jnp.where(ok, v_ref[qc, 0], jnp.float32(0.0))
-
-    w_ref[...] = _gather_rows(idx, read)
+    d_loc = v_ref.shape[0] * v_ref.shape[1]
+    _gather_rows(idx_s, w_ref,
+                 lambda p: _read_v(v_ref, *_owned(p, lo, d_loc)))
 
 
-def _sharded_kernel(obj: Objective, idx_ref, val_ref, y_ref, a_ref,
-                    q_ref, w_ref, scal_ref, lo_ref, v_ref, aout_ref,
-                    vout_ref):
+def _sharded_kernel(obj: Objective, idx_s, idx_ref, val_ref, y_ref, a_ref,
+                    q_ref, w_in_ref, scal_ref, lo_ref, v_hbm, aout_ref,
+                    v_ref, w_ref, u_ref):
     """One bucket's recursion + owned-slice scatter, given the
     EXCHANGED working set W (full bits on every lane)."""
-    vout_ref[...] = v_ref[...]
-    idx = idx_ref[...]                          # (B, nnz) int32
-    vals = val_ref[...].astype(jnp.float32)     # (B, nnz)
-    y = y_ref[0].astype(jnp.float32)            # (B,)
-    a0 = a_ref[0].astype(jnp.float32)           # (B,)
-    qrow = q_ref[0].astype(jnp.float32)         # (B,)
-    W = w_ref[...].astype(jnp.float32)          # (B, nnz)
-    lam_n = scal_ref[0]
-    sig = scal_ref[1]
+    pltpu.sync_copy(v_hbm, v_ref)
+    w_ref[...] = w_in_ref[...].astype(jnp.float32)
+    a0 = a_ref[...].astype(jnp.float32)                     # (1, B)
     lo = lo_ref[0]
-    B, nnz = idx.shape
-    d_loc = v_ref.shape[0]
+    d_loc = v_ref.shape[0] * v_ref.shape[1]
 
     # every lane runs the full recursion on the same W bits (redundant
     # O(B*nnz) VPU work — the price of one exchange per bucket)
-    U, deltas = _bucket_recursion(obj, idx, vals, y, a0, qrow, lam_n,
-                                  sig, W)
+    deltas = _bucket_recursion(
+        obj, idx_s, idx_ref[...], val_ref, y_ref[...].astype(jnp.float32),
+        a0, q_ref[...].astype(jnp.float32), scal_ref[0], scal_ref[1],
+        w_ref, u_ref)
 
     # scatter the OWNED entries in visiting order; unowned writes put
-    # the unchanged bits back (no FP op), so each v row accumulates its
+    # the unchanged row back (no FP op), so each v entry accumulates its
     # hits in exactly the replicated kernel's sequence on its one owner
-    def scatter(t, carry):
-        i = t // nnz
-        k = t - i * nnz
-        p = jax.lax.dynamic_slice(idx, (i, k), (1, 1))[0, 0] - lo
-        ok = jnp.logical_and(p >= 0, p < d_loc)
-        pc = jnp.where(ok, p, 0)
-        u = jax.lax.dynamic_slice(U, (i, k), (1, 1))[0, 0]
-        cur = vout_ref[pc, 0]
-        vout_ref[pc, 0] = jnp.where(ok, cur + u, cur)
-        return carry
+    def add(p, u):
+        q, ok = _owned(p, lo, d_loc)
+        _add_v(v_ref, q, u, ok)
 
-    jax.lax.fori_loop(0, B * nnz, scatter, 0)
-    aout_ref[0] = (a0 + deltas).astype(aout_ref.dtype)
+    _scatter_rows(idx_s, u_ref, add)
+    aout_ref[...] = (a0 + deltas).astype(aout_ref.dtype)
 
 
-def _check_sharded_tile(B: int, nnz: int, d_loc: int, source: str):
+def _check_sharded_tile(B: int, nnz: int, v_loc_shape, source: str):
     if B % 8 or nnz % 8:
         raise ValueError(
             f"sparse bucket tiles from {source} have (B={B}, nnz={nnz}); "
             f"the sharded Pallas kernel needs both to be multiples of 8 "
             f"(f32 sublane tile) — rebuild the tile cache with "
             f"nnz_multiple=8 or zero-pad ad-hoc idx/val arrays.")
-    if d_loc % 8:
+    rows, lanes = v_loc_shape
+    if lanes != LANES or rows % 8:
         raise ValueError(
-            f"v slice from {source} has d_loc={d_loc}, which must be a "
+            f"v slice from {source} has shape {tuple(v_loc_shape)}; the "
+            f"kernel needs a lane-dense (rows, {LANES}) slice with rows a "
             f"multiple of 8 (ops.sdca_sparse_sharded_subepoch sizes "
-            f"slices to the sublane tile automatically)")
-    if d_loc * 4 > V_VMEM_BUDGET_BYTES:
+            f"slices to the tile automatically)")
+    d_loc = rows * lanes
+    if v_bytes(d_loc) > V_VMEM_BUDGET_BYTES:
         raise ValueError(
-            f"per-lane v slice of d_loc={d_loc} rows ({d_loc * 4} bytes) "
-            f"exceeds the sparse kernel's VMEM budget "
-            f"({V_VMEM_BUDGET_BYTES} bytes) even feature-sharded.  Add "
-            f"model-axis lanes or use local_solver='xla' "
+            f"per-lane v slice of d_loc={d_loc} features "
+            f"({v_bytes(d_loc)} bytes) exceeds the sparse kernel's VMEM "
+            f"budget ({V_VMEM_BUDGET_BYTES} bytes) even feature-sharded.  "
+            f"Add model-axis lanes or use local_solver='xla' "
             f"(HBM-resident v).")
     need = vmem_bytes_estimate_sharded(B, nnz, d_loc)
     if need > TOTAL_VMEM_BUDGET_BYTES:
         raise ValueError(
             f"sharded sparse bucket tiles from {source} with (B={B}, "
-            f"nnz={nnz}, d_loc={d_loc}) need ~{need} bytes of VMEM — "
-            f"the per-coordinate (B, nnz, nnz) match tensor alone is "
-            f"{B * nnz * nnz * 5} bytes — over the kernel's "
-            f"{TOTAL_VMEM_BUDGET_BYTES}-byte total budget.  Use "
-            f"local_solver='xla' for this workload, or shrink "
+            f"nnz={nnz}, d_loc={d_loc}) need ~{need} bytes of VMEM "
+            f"(resident v slice + (B, nnz) tiles + working sets), over "
+            f"the kernel's {TOTAL_VMEM_BUDGET_BYTES}-byte total budget.  "
+            f"Use local_solver='xla' for this workload, or shrink "
             f"bucket/nnz so the tiles fit.")
 
 
@@ -426,25 +474,22 @@ def sdca_sparse_gather_bucket(idx_t: Array, v_loc: Array, lo: Array,
                               source: str = "ad-hoc arrays") -> Array:
     """Gather ONE bucket's per-lane partial working set.
 
-    idx_t: (B, nnz) int32 feature ids; v_loc: (d_loc, 1) f32 this
-    lane's v slice; lo: () int32 the slice's first global row.  Returns
-    W_loc (B, nnz) f32 with this lane's rows and exact zeros elsewhere.
+    idx_t: (B, nnz) int32 feature ids; v_loc: (d_loc / 128, 128) f32
+    this lane's lane-dense v slice; lo: () int32 the slice's first
+    global feature.  Returns W_loc (B, nnz) f32 with this lane's
+    entries and exact zeros elsewhere.
     """
     B, nnz = idx_t.shape
-    d_loc = v_loc.shape[0]
-    _check_sharded_tile(B, nnz, d_loc, source)
+    _check_sharded_tile(B, nnz, v_loc.shape, source)
     return pl.pallas_call(
         _gather_slice_kernel,
-        grid=(1,),
         in_specs=[
-            pl.BlockSpec((B, nnz), lambda i: (0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((d_loc, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((B, nnz), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((B, nnz), jnp.float32),
-        compiler_params=_compiler_params(
-            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(idx_t, lo.astype(jnp.int32).reshape(1), v_loc)
 
@@ -459,39 +504,28 @@ def sdca_sparse_sharded_bucket(obj: Objective, idx_t: Array, val_t: Array,
     """Run ONE bucket's recursion + owned scatter on the v slice.
 
     idx_t/val_t: (B, nnz); y_t/a_t/q_t: (B,); W: (B, nnz) the EXCHANGED
-    full working set (every lane the same bits); v_loc: (d_loc, 1) this
-    lane's slice (aliased into the output); scal: (2,) [lam*n, sigma'];
-    lo: () int32.  Returns (a_new (B,), v_loc_new (d_loc, 1)).
+    full working set (every lane the same bits); v_loc: (d_loc / 128,
+    128) this lane's lane-dense slice (aliased into the output); scal:
+    (2,) [lam*n, sigma']; lo: () int32.  Returns (a_new (B,),
+    v_loc_new like v_loc).
     """
     B, nnz = idx_t.shape
-    d_loc = v_loc.shape[0]
-    _check_sharded_tile(B, nnz, d_loc, source)
+    _check_sharded_tile(B, nnz, v_loc.shape, source)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     a_new, v_fin = pl.pallas_call(
         functools.partial(_sharded_kernel, obj),
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((B, nnz), lambda i: (0, 0)),
-            pl.BlockSpec((B, nnz), lambda i: (0, 0)),
-            pl.BlockSpec((1, B), lambda i: (0, 0)),
-            pl.BlockSpec((1, B), lambda i: (0, 0)),
-            pl.BlockSpec((1, B), lambda i: (0, 0)),
-            pl.BlockSpec((B, nnz), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((d_loc, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, B), lambda i: (0, 0)),
-            pl.BlockSpec((d_loc, 1), lambda i: (0, 0)),
-        ],
+        in_specs=[smem, vmem, vmem, vmem, vmem, vmem, vmem, smem, smem,
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[vmem, vmem],
         out_shape=[
             jax.ShapeDtypeStruct((1, B), a_t.dtype),
-            jax.ShapeDtypeStruct((d_loc, 1), jnp.float32),
+            jax.ShapeDtypeStruct(v_loc.shape, jnp.float32),
         ],
-        input_output_aliases={8: 1},   # v slice reused as output
-        compiler_params=_compiler_params(
-            dimension_semantics=("arbitrary",)),
+        scratch_shapes=[pltpu.VMEM((B, nnz), jnp.float32),
+                        pltpu.VMEM((B, nnz), jnp.float32)],
+        input_output_aliases={9: 1},   # v slice reused as output
         interpret=interpret,
-    )(idx_t, val_t, y_t[None], a_t[None], q_t[None], W, scal,
+    )(idx_t, idx_t, val_t, y_t[None], a_t[None], q_t[None], W, scal,
       lo.astype(jnp.int32).reshape(1), v_loc)
     return a_new[0], v_fin

@@ -47,9 +47,9 @@ from repro.core import engine
 from repro.core.config import AlgoConfig, DeploymentConfig, EngineConfig
 from repro.core.objectives import LOGISTIC, Objective
 
-# The version-compat shard_map wrapper (check_vma/check_rep off — see
-# the note in core/engine.py) moved into the engine with the streamed
-# mesh path; re-exported here for existing importers.
+# The shard_map wrapper (check_vma off — see the note in
+# core/engine.py) lives in the engine with the streamed mesh path;
+# re-exported here for existing importers.
 from repro.core.engine import shard_map  # noqa: F401  (re-export)
 
 

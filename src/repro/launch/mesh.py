@@ -18,7 +18,7 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import AbstractMesh
+from jax.sharding import AbstractMesh, AxisType
 
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per v5e chip
 HBM_BW = 819e9               # bytes/s per chip
@@ -31,18 +31,19 @@ from repro.core.planner import H2D_BW  # noqa: E402,F401
 
 
 def abstract_mesh(shape, axis_names) -> AbstractMesh:
-    """Version-compatible AbstractMesh constructor.
+    """Device-free mesh with `Auto` axes, like the meshes below — for
+    validating the production 256/512-chip shardings without device
+    allocation."""
+    return AbstractMesh(tuple(shape), tuple(axis_names),
+                        axis_types=(AxisType.Auto,) * len(shape))
 
-    Newer jax takes `AbstractMesh(shape, axis_names)`; jax <= 0.4.x
-    takes a single `shape_tuple` of (name, size) pairs.  Tests and
-    spec-checking code should use this instead of the raw class so the
-    production 256/512-chip shardings can be validated without device
-    allocation on any supported jax.
-    """
-    try:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
+
+def _auto_mesh(shape, axes, devices):
+    # jax.make_mesh defaults to Explicit axes, under which
+    # with_sharding_constraint and sharded contractions outside
+    # shard_map raise; the engine is written for Auto (GSPMD) axes.
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -55,19 +56,17 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for {shape}, have {len(devs)}; the dry-run "
             "entrypoint must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before any jax import")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return _auto_mesh(shape, axes, devs[:n])
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1, pod: int = 1):
     """Small mesh over however many (host) devices exist — for tests."""
-    shape = tuple(s for s in (pod, data, model))
-    axes = ("pod", "data", "model")
-    keep = [i for i, s in enumerate(shape)]
+    shape = (pod, data, model)
     n = math.prod(shape)
     devs = jax.devices()
     if len(devs) < n:
         raise RuntimeError(f"need {n} devices, have {len(devs)}")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return _auto_mesh(shape, ("pod", "data", "model"), devs[:n])
 
 
 def mesh_chips(mesh) -> int:

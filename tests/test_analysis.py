@@ -33,13 +33,13 @@ def _run(code: str, timeout=600):
 def test_loop_closure_regression_pair():
     """The shard_map loop-invariant-replicated closure bug: a fori_loop
     body closing over an axis_index-derived offset MUST be flagged, and
-    the carry-threaded form of the same program MUST pass.  This is the
+    the xs-threaded form of the same program MUST pass.  This is the
     auditor-level pin of the bug `engine.run_epoch` unrolls its chunk
-    loop to avoid and `ops.sdca_sparse_sharded_subepoch` threads `lo`
-    through its scan carry to avoid."""
+    loop to avoid and `ops.sdca_sparse_sharded_subepoch` passes `lo`
+    as scanned xs to avoid."""
     r = _run("""
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from repro.core.engine import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.analysis import jaxpr_audit, rules
         from repro.launch.mesh import make_host_mesh
@@ -47,8 +47,7 @@ def test_loop_closure_regression_pair():
         mesh = make_host_mesh(pod=1, data=2, model=1)
 
         def trace(inner):
-            f = shard_map(inner, mesh=mesh, in_specs=P("data"),
-                          out_specs=P("data"))
+            f = shard_map(inner, mesh, P("data"), P("data"))
             return jax.make_jaxpr(f)(jnp.zeros(8))
 
         def buggy(x):
@@ -59,10 +58,10 @@ def test_loop_closure_regression_pair():
 
         def threaded(x):
             lo = jax.lax.axis_index("data") * 4
-            def body(i, carry):
-                acc, lo = carry
-                return acc + x[lo + i], lo  # threaded through the carry
-            return jax.lax.fori_loop(0, 4, body, (0.0, lo))[0][None]
+            def body(acc, lo_i):            # a per-iteration input
+                return acc + x[lo_i], None
+            los = lo + jnp.arange(4)
+            return jax.lax.scan(body, 0.0, los)[0][None]
 
         got = jaxpr_audit.audit_jaxpr(trace(buggy), deterministic=True)
         assert [f.rule for f in got] == [rules.JAX_LOOP_CLOSURE], got
@@ -97,7 +96,7 @@ def test_psum_and_nondet_detectors_fire():
     deterministic contract and ignored outside it."""
     r = _run("""
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from repro.core.engine import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.analysis import jaxpr_audit, rules
         from repro.launch.mesh import make_host_mesh
@@ -105,8 +104,8 @@ def test_psum_and_nondet_detectors_fire():
         mesh = make_host_mesh(pod=1, data=2, model=1)
         for fn, rule in [(jax.lax.psum, rules.JAX_PSUM_EXCHANGE),
                          (jax.lax.pmax, rules.JAX_NONDET_PRIM)]:
-            f = shard_map(lambda x, fn=fn: fn(x, "data"), mesh=mesh,
-                          in_specs=P("data"), out_specs=P(None))
+            f = shard_map(lambda x, fn=fn: fn(x, "data"), mesh,
+                          P("data"), P(None))
             j = jax.make_jaxpr(f)(jnp.zeros(8))
             det = jaxpr_audit.audit_jaxpr(j, deterministic=True)
             assert [x.rule for x in det] == [rule], (rule, det)
@@ -222,9 +221,9 @@ def test_budget_audit_clean_and_catches_forged_plan():
     assert found == [], [str(f) for f in found]
     assert n_plans > 500          # the sweep actually swept
 
-    sig = WorkloadSignature(n=4096, d=64, nnz=512, sparse=True)
+    sig = WorkloadSignature(n=4096, d=64, nnz=2048, sparse=True)
     forged = SolverPlan(solver="pallas", route="pallas-replicated",
-                        bucket=16, chunks=1, nnz_multiple=0,
+                        bucket=512, chunks=1, nnz_multiple=0,
                         feature_shard=False)
     got = budget.audit_plan(sig, Topology(backend="tpu"), forged)
     assert got and all(f.rule == rules.VMEM_PLAN_BUDGET for f in got)
@@ -247,7 +246,7 @@ def test_misfit_reasons_carry_stable_codes():
     assert reason.code == ops.MisfitCode.ALIGNMENT
     _, reason = ops.sparse_solver_plan(16, 8, 3_000_000, 16)
     assert reason.code == ops.MisfitCode.VMEM_V
-    _, reason = ops.sparse_solver_plan(16, 512, 64, 16)
+    _, reason = ops.sparse_solver_plan(512, 2048, 64, 512)
     assert reason.code == ops.MisfitCode.VMEM_TOTAL
 
     why = ops.dense_kernel_misfit(64, 1024, 1024)
@@ -256,8 +255,8 @@ def test_misfit_reasons_carry_stable_codes():
 
     # planner surface: infeasible geometry -> code on the plan;
     # feasible -> empty code, reason "fits"
-    sig = WorkloadSignature(n=4096, d=64, nnz=512, sparse=True)
-    plan = static_plan(sig, Topology(backend="tpu"), bucket=16)
+    sig = WorkloadSignature(n=4096, d=64, nnz=2048, sparse=True)
+    plan = static_plan(sig, Topology(backend="tpu"), bucket=512)
     assert plan.route == "xla"
     assert plan.reason_code == ops.MisfitCode.VMEM_TOTAL
     assert type(plan.reason) is str       # JSON-plain on the record

@@ -451,9 +451,10 @@ def test_sparse_pallas_local_solver_on_mesh_path():
     # alignment and divisibility misfits beat everything
     (64, 7, 4_096, 8, 4, "xla", "multiples of 8"),
     (12, 8, 4_096, 8, 4, "xla", "divide"),
-    # wide rows: the (B, nnz, nnz) match tensor blows the TOTAL budget
-    # for replicated AND sharded alike — sharding v doesn't shrink it
-    (64, 512, 4_096, 16, 2, "xla", "total budget"),
+    # wide tiles: the double-buffered (B, nnz) tiles blow the TOTAL
+    # budget for replicated AND sharded alike — sharding v doesn't
+    # shrink them
+    (512, 2048, 4_096, 512, 2, "xla", "total budget"),
 ])
 def test_sparse_solver_plan_decision_table(n_local, nnz, d, B, M, route,
                                            reason_part):

@@ -244,19 +244,19 @@ def test_sdca_sparse_kernel_vmem_budget_guard():
 
 
 def test_sdca_sparse_kernel_total_vmem_budget_guard():
-    """Wide tiles whose (B, nnz, nnz) match tensor blows the TOTAL VMEM
-    budget get the same actionable ValueError narrow workloads do, not
-    an opaque Mosaic OOM (v alone is tiny here: B=16, nnz=512 puts the
-    match tensor at 16 MiB)."""
+    """Wide tiles whose footprint blows the TOTAL VMEM budget get the
+    same actionable ValueError narrow workloads do, not an opaque Mosaic
+    OOM (v alone is tiny here: B=512, nnz=2048 puts the double-buffered
+    idx/val tiles at 16 MiB)."""
     from repro.kernels.sdca_sparse_bucket import (
         TOTAL_VMEM_BUDGET_BYTES, vmem_bytes_estimate)
-    B, nnz, d = 16, 512, 64
+    B, nnz, d = 512, 2048, 64
     assert vmem_bytes_estimate(B, nnz, 64) > TOTAL_VMEM_BUDGET_BYTES
     idx = jnp.zeros((B, nnz), jnp.int32)
     val = jnp.zeros((B, nnz), jnp.float32)
     y = jnp.ones(B, jnp.float32)
     a = jnp.zeros(B, jnp.float32)
-    with pytest.raises(ValueError, match="match tensor"):
+    with pytest.raises(ValueError, match="total budget"):
         ops.sdca_sparse_bucket_subepoch(
             LOGISTIC, idx, val, y, a, jnp.zeros(d, jnp.float32),
             jnp.float32(1.0), jnp.float32(1.0), bucket=B, interpret=True)
@@ -396,12 +396,12 @@ def test_sdca_sparse_sharded_multilane_emulated_exchange(obj, M):
 
     d_loc = ops.sparse_slice_width(d, M)
     d_pad = d_loc * M
-    v_pad = jnp.zeros((d_pad, 1), jnp.float32).at[:d, 0].set(v0)
-    v_locs = [v_pad[k * d_loc:(k + 1) * d_loc] for k in range(M)]
+    v_pad = jnp.zeros((d_pad,), jnp.float32).at[:d].set(v0)
+    v_locs = [v_pad[k * d_loc:(k + 1) * d_loc].reshape(-1, 128)
+              for k in range(M)]
     v0_locs = list(v_locs)
     scal = jnp.stack([lam_n, sig])
-    valf = val.astype(jnp.float32)
-    q = jnp.sum(valf * valf, axis=1)
+    q = core_sdca.row_sq_norms(val.astype(jnp.float32))
     a_rows = []
     for b in range(n // B):
         sl = slice(b * B, (b + 1) * B)
@@ -424,7 +424,7 @@ def test_sdca_sparse_sharded_multilane_emulated_exchange(obj, M):
             np.testing.assert_array_equal(other, a_lanes[0])
         a_rows.append(a_lanes[0])
     dv = jnp.concatenate(
-        [(v_locs[k] - v0_locs[k])[:, 0] for k in range(M)])[:d] / sig
+        [(v_locs[k] - v0_locs[k]).reshape(-1) for k in range(M)])[:d] / sig
     np.testing.assert_array_equal(np.concatenate(a_rows),
                                   np.asarray(a_ref))
     np.testing.assert_array_equal(np.asarray(dv), np.asarray(dv_ref))
@@ -441,26 +441,26 @@ def test_sdca_sparse_sharded_kernel_guards():
     idx_t = jnp.zeros((B, nnz), jnp.int32)
     lo = jnp.int32(0)
     # slice rows over the resident budget even after sharding
-    d_big = V_VMEM_BUDGET_BYTES // 4 + 8
+    rows_big = V_VMEM_BUDGET_BYTES // (4 * 128) + 8
     with pytest.raises(ValueError, match="even feature-sharded"):
         sdca_sparse_bucket.sdca_sparse_gather_bucket(
-            idx_t, jnp.zeros((d_big, 1), jnp.float32), lo, True)
-    # slice not sublane-aligned (the driver always aligns; direct
+            idx_t, jnp.zeros((rows_big, 128), jnp.float32), lo, True)
+    # slice not tile-aligned (the ops wrapper always aligns; direct
     # callers get told who is responsible)
     with pytest.raises(ValueError, match="multiple of 8"):
         sdca_sparse_bucket.sdca_sparse_gather_bucket(
-            idx_t, jnp.zeros((12, 1), jnp.float32), lo, True)
-    # (B, nnz, nnz) match tensor blows the total budget
-    Bw, nnzw = 16, 512
-    assert (vmem_bytes_estimate_sharded(Bw, nnzw, 64)
+            idx_t, jnp.zeros((12, 128), jnp.float32), lo, True)
+    # wide (B, nnz) tiles blow the total budget
+    Bw, nnzw = 512, 2048
+    assert (vmem_bytes_estimate_sharded(Bw, nnzw, 1024)
             > TOTAL_VMEM_BUDGET_BYTES)
-    with pytest.raises(ValueError, match="match tensor"):
+    with pytest.raises(ValueError, match="total budget"):
         sdca_sparse_bucket.sdca_sparse_sharded_bucket(
             LOGISTIC, jnp.zeros((Bw, nnzw), jnp.int32),
             jnp.zeros((Bw, nnzw), jnp.float32), jnp.ones(Bw),
             jnp.zeros(Bw), jnp.zeros(Bw),
             jnp.zeros((Bw, nnzw), jnp.float32),
-            jnp.zeros((64, 1), jnp.float32),
+            jnp.zeros((8, 128), jnp.float32),
             jnp.stack([jnp.float32(1.0), jnp.float32(1.0)]), lo, True)
 
 

@@ -75,7 +75,7 @@ def test_svmlight_roundtrip_property():
     from hypothesis import given, settings, strategies as st
 
     f32 = st.floats(width=32, allow_nan=False, allow_infinity=False,
-                    min_value=1e-6, max_value=1e6)
+                    min_value=float(np.float32(1e-6)), max_value=1e6)
 
     @given(st.lists(st.lists(st.tuples(st.integers(0, 999), f32),
                              min_size=0, max_size=8,
