@@ -10,8 +10,7 @@ Everything older (`GLMTrainer`, `StreamedGLMTrainer`, `fit_dataset`,
 `cocoa.epoch_sim*`) is a deprecation shim over these — see the
 migration map in DESIGN.md S10 and `ReproDeprecationWarning`.
 """
-from .callbacks import (BenchmarkRecorder, Callback, CheckpointHook,
-                        EarlyStopping, GapLogger)
+from .callbacks import Callback, CheckpointHook, EarlyStopping, GapLogger
 from .deprecation import ReproDeprecationWarning, warn_deprecated
 from .estimators import (GLMEstimator, LinearSVC, LogisticRegression,
                          NotFittedError, Ridge, load)
@@ -21,8 +20,7 @@ from .session import Session, margins
 from repro.resilience import HealthMonitor, HealthPolicy
 
 __all__ = [
-    "BenchmarkRecorder", "Callback", "CheckpointHook", "EarlyStopping",
-    "GapLogger",
+    "Callback", "CheckpointHook", "EarlyStopping", "GapLogger",
     "ReproDeprecationWarning", "warn_deprecated",
     "GLMEstimator", "LinearSVC", "LogisticRegression", "NotFittedError",
     "Ridge", "load",

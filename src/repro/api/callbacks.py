@@ -16,11 +16,9 @@ current epoch.  A bare callable works too.  Two optional extensions:
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, Optional
 
-__all__ = ["Callback", "EarlyStopping", "GapLogger", "CheckpointHook",
-           "BenchmarkRecorder"]
+__all__ = ["Callback", "EarlyStopping", "GapLogger", "CheckpointHook"]
 
 
 class Callback:
@@ -125,27 +123,3 @@ class CheckpointHook(Callback):
             return
         self.mgr.save(ep, self.session.state_dict(),
                       meta=dict(self.meta, epoch=ep))
-
-
-class BenchmarkRecorder(Callback):
-    """Collect per-epoch records (+ wall-clock) for benchmark emitters —
-    what fig3/fig6's estimator arms feed from."""
-
-    def __init__(self):
-        self.records: list[dict] = []
-        self._t0 = time.perf_counter()
-
-    def bind(self, session) -> None:
-        """Attach the session and restart the wall clock."""
-        super().bind(session)
-        self._t0 = time.perf_counter()
-
-    def on_epoch_end(self, metrics: dict) -> None:
-        """Append this epoch's metrics stamped with elapsed wall time."""
-        self.records.append(
-            dict(metrics, wall=time.perf_counter() - self._t0))
-
-    @property
-    def wall_time(self) -> float:
-        """Wall-clock seconds from bind to the latest recorded epoch."""
-        return self.records[-1]["wall"] if self.records else 0.0

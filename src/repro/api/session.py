@@ -13,8 +13,9 @@ jitted epoch program), and exposes epoch-level control:
     s.fit(max_epochs=5)       # ... or 5 more epochs from wherever we are
 
 `fit` drives a callback protocol (`on_epoch_end(metrics) -> stop?`,
-see `repro.api.callbacks`) used for early stopping, gap logging,
-checkpoint hooks, and benchmark recording.  The sklearn-style
+see `repro.api.callbacks`) used for early stopping, gap logging and
+checkpoint hooks, and opens the `repro.*` spans and counters of
+`repro.obs` at its boundaries (docs/tracing.md).  The sklearn-style
 estimators in `repro.api.estimators` are thin facades over a Session;
 the legacy trainers are deprecation shims over it (DESIGN.md S10).
 
@@ -41,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import engine, objectives
 from repro.core.bucketing import BucketPlan, make_plan
 from repro.core.config import EngineConfig, as_engine_config
@@ -507,30 +509,37 @@ class Session:
         `fit` the same record's 't' is rewritten to the cumulative
         fit wall-clock (one shared record, also kept in `history`)."""
         t0 = time.perf_counter()
-        if self._faults is not None:
-            # deterministic fault probes ($REPRO_FAULTS / tests):
-            # epoch-boundary kill, kernel failure on pallas routes,
-            # post-epoch NaN poisoning (the resident twin of nan-chunk)
-            self._faults.maybe_kill(self.epochs_done)
-            if self.spec.algo.local_solver != "xla":
-                self._faults.maybe_kernel_fail(self.epochs_done)
-        v_prev = self.v
-        self.alpha, self.v = self._epoch_fn(
-            self.alpha, self.v, jnp.int32(self.epochs_done))
-        if self._faults is not None \
-                and self._faults.nan_epoch(self.epochs_done):
-            self.v = self.v * jnp.float32(float("nan"))
-        self.epochs_done += 1
-        if self._journal is not None:
-            self._journal.commit_epoch(self.alpha, self.v,
-                                       self.epochs_done)
-        rel = float(jnp.linalg.norm(self.v - v_prev)
-                    / jnp.maximum(jnp.linalg.norm(self.v), 1e-30))
+        e = self.epochs_done
+        with obs.span("epoch", step_num=e):
+            if self._faults is not None:
+                # deterministic fault probes ($REPRO_FAULTS / tests):
+                # epoch-boundary kill, kernel failure on pallas routes,
+                # post-epoch NaN poisoning (the resident twin of nan-chunk)
+                self._faults.maybe_kill(e)
+                if self.spec.algo.local_solver != "xla":
+                    self._faults.maybe_kernel_fail(e)
+            v_prev = self.v
+            with obs.span("epoch.program", epoch=e):
+                self.alpha, self.v = self._epoch_fn(
+                    self.alpha, self.v, jnp.int32(e))
+            if self._faults is not None and self._faults.nan_epoch(e):
+                self.v = self.v * jnp.float32(float("nan"))
+            self.epochs_done += 1
+            if self._journal is not None:
+                with obs.span("journal", epoch=e):
+                    self._journal.commit_epoch(self.alpha, self.v,
+                                               self.epochs_done)
+            rel = obs.read(
+                "rel_change",
+                lambda: jnp.linalg.norm(self.v - v_prev)
+                / jnp.maximum(jnp.linalg.norm(self.v), 1e-30), epoch=e)
+            obs.add("epochs")
         rec = {"epoch": self.epochs_done, "rel_change": rel,
                "t": time.perf_counter() - t0}
         self.history.append(rec)
         return rec
 
+    @obs.span("fit")
     def fit(self, *, until: Optional[int] = None,
             max_epochs: Optional[int] = None, tol: float = 1e-3,
             gap_every: int = 0, callbacks: Sequence = (),
@@ -598,7 +607,9 @@ class Session:
             rec["t"] = time.perf_counter() - t0
             want_gap = needs_gap or (
                 gap_every and self.epochs_done % gap_every == 0)
-            vmax = float(jnp.max(jnp.abs(self.v)))
+            e = self.epochs_done - 1
+            vmax = obs.read("vmax", lambda: jnp.max(jnp.abs(self.v)),
+                            epoch=e)
             if not np.isfinite(vmax) or vmax > diverge_above:
                 if monitor is None:
                     diverged = True
@@ -613,9 +624,10 @@ class Session:
                       f"rel={rec['rel_change']:.3e} "
                       + (f"gap={rec['gap']:.3e}" if "gap" in rec else ""))
             stop = False
-            for cb in cbs:
-                fn = getattr(cb, "on_epoch_end", cb)
-                stop = bool(fn(rec)) or stop
+            with obs.span("callbacks", epoch=e):
+                for cb in cbs:
+                    fn = getattr(cb, "on_epoch_end", cb)
+                    stop = bool(fn(rec)) or stop
             if rec["rel_change"] < tol:
                 converged = True
                 break
@@ -631,11 +643,12 @@ class Session:
                         "t": 0.0, "gap": self.gap()}]
         elif "gap" not in history[-1]:
             history[-1]["gap"] = self.gap() if not diverged else float("inf")
+        e = self.epochs_done - 1
         return FitResult(
             epochs=self.epochs_done, converged=converged,
-            diverged=diverged, v=np.asarray(self.v),
-            alpha=np.asarray(self.alpha), history=history,
-            wall_time=time.perf_counter() - t0)
+            diverged=diverged, v=obs.read("result", self.v, epoch=e),
+            alpha=obs.read("result", self.alpha, epoch=e),
+            history=history, wall_time=time.perf_counter() - t0)
 
     # -- diagnostics -------------------------------------------------------
 
@@ -653,8 +666,9 @@ class Session:
         src = self.cache if self.cache is not None else self.feed
         nb = self.bplan.n_buckets
         B = self.bplan.bucket
+        e = self.epochs_done - 1
         loss_sum = conj_sum = 0.0
-        alpha = np.asarray(self.alpha)
+        alpha = obs.read("dual", self.alpha, epoch=e)
         v = self.v
         for start in range(0, nb, gbuckets):
             bids = np.arange(start, min(start + gbuckets, nb))
@@ -671,10 +685,13 @@ class Session:
                 data, yb = hf(bids) if hf is not None else src.fetch(bids)
             yb = jnp.asarray(yb)
             m = margins(v, data)
-            loss_sum += float(jnp.sum(self.obj.loss(m, yb)))
+            loss_sum += obs.read(
+                "primal", lambda: jnp.sum(self.obj.loss(m, yb)), epoch=e)
             a = jnp.asarray(alpha[start * B:start * B + yb.shape[0]])
-            conj_sum += float(jnp.sum(self.obj.conj_neg(a, yb)))
-        reg = 0.5 * self.lam * float(jnp.sum(v ** 2))
+            conj_sum += obs.read(
+                "dual", lambda: jnp.sum(self.obj.conj_neg(a, yb)), epoch=e)
+        reg = 0.5 * self.lam * obs.read("primal", lambda: jnp.sum(v ** 2),
+                                        epoch=e)
         primal = loss_sum / self.n + reg
         dual = -conj_sum / self.n - reg
         return primal, dual
@@ -683,24 +700,30 @@ class Session:
         """Primal objective P(v) at the current shared vector."""
         if self.streamed:
             return self._streamed_primal_dual()[0]
+        e = self.epochs_done - 1
         if self.sparse:
             m = margins(self.v, (self.idx, self.val))
-            return float(jnp.sum(self.obj.loss(m, self.y)) / self.n
-                         + 0.5 * self.lam * jnp.sum(self.v ** 2))
-        return float(objectives.primal_value(
-            self.obj, self.v, self.X, self.y, self.lam))
+            return obs.read(
+                "primal", lambda: jnp.sum(self.obj.loss(m, self.y)) / self.n
+                + 0.5 * self.lam * jnp.sum(self.v ** 2), epoch=e)
+        return obs.read("primal", lambda: objectives.primal_value(
+            self.obj, self.v, self.X, self.y, self.lam), epoch=e)
 
     def gap(self) -> float:
         """Duality gap P(v) - D(alpha) — the convergence certificate."""
-        if self.streamed:
-            p, dv = self._streamed_primal_dual()
-            return p - dv
-        if self.sparse:
-            dval = objectives.dual_value(self.obj, self.alpha, self.v,
-                                         self.y, self.lam)
-            return self.primal() - float(dval)
-        return float(objectives.duality_gap(
-            self.obj, self.alpha, self.v, self.X, self.y, self.lam))
+        e = self.epochs_done - 1
+        with obs.span("gap", epoch=e):
+            obs.add("gap_checks")
+            if self.streamed:
+                p, dv = self._streamed_primal_dual()
+                return p - dv
+            if self.sparse:
+                dval = objectives.dual_value(self.obj, self.alpha, self.v,
+                                             self.y, self.lam)
+                return self.primal() - obs.read("dual", dval, epoch=e)
+            return obs.read("gap", lambda: objectives.duality_gap(
+                self.obj, self.alpha, self.v, self.X, self.y, self.lam),
+                epoch=e)
 
     # -- checkpoint/restart ------------------------------------------------
 
@@ -711,8 +734,11 @@ class Session:
 
     def load_state_dict(self, st: dict[str, Any]) -> None:
         """Restore training state produced by `state_dict`."""
-        self.alpha = jnp.asarray(st["alpha"])
-        self.v = jnp.asarray(st["v"])
+        with obs.span("state.load"):
+            obs.add("h2d_bytes", sum(st[k].nbytes for k in ("alpha", "v")
+                                     if isinstance(st[k], np.ndarray)))
+            self.alpha = jnp.asarray(st["alpha"])
+            self.v = jnp.asarray(st["v"])
         self.epochs_done = int(st["epoch"])
 
     def save(self, path, *, meta: Optional[dict] = None) -> None:

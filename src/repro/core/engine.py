@@ -60,6 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from . import sdca
 from .config import AlgoConfig, EngineConfig, as_engine_config
 from .objectives import Objective
@@ -1071,18 +1073,21 @@ def run_epoch_streamed(
     sched = np.asarray(plan.schedule(ep))           # (P, K, per_lane)
 
     def fetch(c):
-        bids = sched[..., c * per_chunk:(c + 1) * per_chunk]
-        cols = (bids[..., None] * B
-                + np.arange(B, dtype=np.int32)).reshape(
-                    bids.shape[:-1] + (per_chunk * B,))
-        data, yc = feed.fetch(bids)
-        return jnp.asarray(cols), data, yc
+        with obs.span("ingest.fetch", epoch=ep, chunk=c):
+            obs.add("chunks")
+            bids = sched[..., c * per_chunk:(c + 1) * per_chunk]
+            cols = (bids[..., None] * B
+                    + np.arange(B, dtype=np.int32)).reshape(
+                        bids.shape[:-1] + (per_chunk * B,))
+            data, yc = feed.fetch(bids)
+            return jnp.asarray(cols), data, yc
 
     v = coll.pod_replicate(v)
     v_in = v
     start = 0
     if journal is not None:
-        got = journal.load_inflight(ep, alpha, v, v_in)
+        with obs.span("journal", epoch=ep):
+            got = journal.load_inflight(ep, alpha, v, v_in)
         if got is not None:
             start, alpha, v, v_in = got
             alpha, v, v_in = (jnp.asarray(alpha), jnp.asarray(v),
@@ -1093,15 +1098,20 @@ def run_epoch_streamed(
         nxt = ex.submit(fetch, start)
         for c in range(start, algo.chunks):
             if journal is not None:
-                journal.pre_chunk(ep, c)
+                with obs.span("journal", epoch=ep, chunk=c):
+                    journal.pre_chunk(ep, c)
             t0 = time.perf_counter()
-            cols, data, yc = nxt.result()
-            wait_s += time.perf_counter() - t0
+            with obs.span("ingest.wait", epoch=ep, chunk=c):
+                cols, data, yc = nxt.result()
+            dt = time.perf_counter() - t0
+            wait_s += dt
+            obs.add("ingest_wait_s", dt)
             if c + 1 < algo.chunks:
                 nxt = ex.submit(fetch, c + 1)
             alpha, v = step(data, yc, cols, alpha, v)
             if journal is not None:
-                journal.post_chunk(ep, c, alpha, v, v_in, algo.chunks)
+                with obs.span("journal", epoch=ep, chunk=c):
+                    journal.post_chunk(ep, c, alpha, v, v_in, algo.chunks)
     v = coll.pod_reduce(v, v_in)
     if stats is not None:
         jax.block_until_ready((alpha, v))
@@ -1477,6 +1487,7 @@ class MeshChunkFeed:
         nbytes += y.nbytes
         yd = jax.device_put(y, self._y_s)
         self.bytes_h2d += nbytes
+        obs.add("h2d_bytes", nbytes)
         self.fetch_s += time.perf_counter() - t0
         self.fetches += 1
         return data, yd

@@ -502,14 +502,19 @@ class TileFeed:
 
     def fetch(self, bids: np.ndarray):
         import jax
+
+        from repro import obs
         if self.verify:
             self.cache.verify_tiles(bids)
         data, y = self.cache.gather_buckets(bids)
         if self.sparse:
             idx, val = data
+            obs.add("h2d_bytes", idx.nbytes + val.nbytes + y.nbytes)
             return ((jax.device_put(idx), jax.device_put(val)),
                     jax.device_put(y))
-        return jax.device_put(np.ascontiguousarray(data)), jax.device_put(y)
+        data = np.ascontiguousarray(data)
+        obs.add("h2d_bytes", data.nbytes + y.nbytes)
+        return jax.device_put(data), jax.device_put(y)
 
 
 class ArrayFeed:
@@ -538,10 +543,16 @@ class ArrayFeed:
 
     def fetch(self, bids: np.ndarray):
         import jax
+
+        from repro import obs
         cols = self._cols(np.asarray(bids))
-        y = jax.device_put(self.y[cols])
+        y = self.y[cols]
         if self.sparse:
-            return ((jax.device_put(self.idx[cols]),
-                     jax.device_put(self.val[cols])), y)
-        data = np.moveaxis(self.X[:, cols], 0, -2)   # (*lead, d, m)
-        return jax.device_put(np.ascontiguousarray(data)), y
+            idx, val = self.idx[cols], self.val[cols]
+            obs.add("h2d_bytes", idx.nbytes + val.nbytes + y.nbytes)
+            return ((jax.device_put(idx), jax.device_put(val)),
+                    jax.device_put(y))
+        data = np.ascontiguousarray(
+            np.moveaxis(self.X[:, cols], 0, -2))   # (*lead, d, m)
+        obs.add("h2d_bytes", data.nbytes + y.nbytes)
+        return jax.device_put(data), jax.device_put(y)

@@ -7,8 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import (BenchmarkRecorder, EarlyStopping, GapLogger,
-                       LinearSVC, LogisticRegression, NotFittedError,
+from repro import obs
+from repro.api import (EarlyStopping, GapLogger, LinearSVC,
+                       LogisticRegression, NotFittedError,
                        ReproDeprecationWarning, Ridge, Session)
 from repro.api import load as load_estimator
 from repro.api.deprecation import reset_deprecation_registry
@@ -118,14 +119,14 @@ def test_session_registry_and_cache_sources(tmp_path):
 def test_callbacks_early_stop_logger_recorder():
     X, y = _dense()
     logger = GapLogger(every=1, printer=None)
-    rec = BenchmarkRecorder()
     stop = EarlyStopping(monitor="gap", threshold=1e-3)
     ses = Session((X, y), lam=1e-2, cfg=DET)
-    res = ses.fit(until=50, tol=0.0, callbacks=[logger, stop, rec])
+    obs.reset()
+    res = ses.fit(until=50, tol=0.0, callbacks=[logger, stop])
     assert res.epochs < 50                      # certificate stop fired
     assert logger.trace and logger.trace[-1][1] < 1e-3
-    assert len(rec.records) == res.epochs
-    assert rec.wall_time > 0
+    assert obs.counters()["epochs"] == res.epochs
+    assert res.wall_time > 0
 
 
 def test_bare_callable_callback_stops():
