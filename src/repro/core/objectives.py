@@ -10,12 +10,16 @@ Each objective provides the scalar dual coordinate update
     delta(m, a, y, q) = argmin_d  phi*(-(a+d), y) + m*d + (q/2) d^2
 
 where m = x_i^T v_local is the current margin and q = sigma' * ||x_i||^2
-/ (lam*n) is the (CoCoA-scaled) curvature.  All functions are
-elementwise/vectorized and jit/vmap/scan-safe.
+/ (lam*n) is the (CoCoA-scaled) curvature.  Ridge and hinge solve it in
+closed form; logistic by NEWTON_STEPS guarded Newton steps in the logit
+of b = (a+d) y, the same float32 ops on every route, so a kernel and
+the scan it is pinned to agree bit for bit when their inputs do.  All
+functions are elementwise/vectorized and jit/vmap/scan-safe.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable
 
 import jax
@@ -24,7 +28,12 @@ import jax.numpy as jnp
 Array = jax.Array
 
 _EPS = 1e-12
-_BISECT_ITERS = 40
+# logistic dual: b = a*y is kept in [_B_LO, _B_HI], t = logit(b) in
+# [-_T_MAX, _T_MAX]; NEWTON_STEPS evaluations of h per coordinate
+_B_LO = 1e-6
+_B_HI = 1.0 - 1e-6
+_T_MAX = math.log(_B_HI / _B_LO)
+NEWTON_STEPS = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,35 +107,68 @@ def _log_conj_neg(a: Array, y: Array) -> Array:
     return _xlogx(b) + _xlogx(1.0 - b)
 
 
-def _log_delta(m: Array, a: Array, y: Array, q: Array) -> Array:
-    """Guarded bisection on the monotone derivative.
+def _logit_bracket(c: Array, q: Array, b0: Array):
+    """First iterate and bracket [lo, hi] of the root of h (see
+    `_log_delta`).  sigma(t) - b0 lies in (-b0, 1 - b0), so the root
+    lies in [-c - q (1 - b0), -c + q b0], cut to the domain; the
+    iteration starts at its midpoint."""
+    lo = jnp.clip(-c - q * (1.0 - b0), -_T_MAX, _T_MAX)
+    hi = jnp.clip(-c + q * b0, -_T_MAX, _T_MAX)
+    return 0.5 * (lo + hi), lo, hi
 
-    g(d)  = phi*(-(a+d)) + m d + q d^2 / 2,   b = (a+d) y in (0, 1)
-    g'(d) = y log(b / (1-b)) + m + q d        (strictly increasing in d)
+
+def _logit_newton(t: Array, c: Array, q: Array, b0: Array):
+    """s = sigma(t), h(t) and the Newton step h / h' at t."""
+    s = 1.0 / (1.0 + jnp.exp(-t))
+    h = t + c + q * (s - b0)
+    return s, h, h / (1.0 + q * s * (1.0 - s))
+
+
+def _logit_step(t: Array, lo: Array, hi: Array, c: Array, q: Array,
+                b0: Array):
+    """One guarded Newton step: returns (t, lo, hi, guarded).
+
+    h is convex for t < 0 and concave for t > 0, so a Newton step that
+    starts on the root's side of 0 and moves toward the root never
+    passes it.  The guard: a step that would cross 0 stops there, and
+    one that leaves the bracket takes its midpoint."""
+    _, h, dt = _logit_newton(t, c, q, b0)
+    lo = jnp.where(h < 0.0, t, lo)
+    hi = jnp.where(h > 0.0, t, hi)
+    tn = t - dt
+    cross = tn * t < 0.0
+    tn = jnp.where(cross, 0.0, tn)
+    out = (tn < lo) | (tn > hi)
+    return jnp.where(out, 0.5 * (lo + hi), tn), lo, hi, cross | out
+
+
+def _logit_update(t: Array, c: Array, q: Array, b0: Array,
+                  y: Array) -> Array:
+    """d from the last iterate, its Newton step taken in b
+    (b = s - s (1-s) h/h'): that keeps the precision which rounding t
+    to float32 would lose where b is near 0 or 1."""
+    s, _, dt = _logit_newton(t, c, q, b0)
+    b = jnp.clip(s - s * (1.0 - s) * dt, _B_LO, _B_HI)
+    return (b - b0) * y
+
+
+def _log_delta(m: Array, a: Array, y: Array, q: Array) -> Array:
+    """Guarded Newton in the logit t = logit(b), b = (a+d) y.
+
+    g'(d) = y logit(b) + m + q d is strictly increasing in d; times y
+    it is  h(t) = t + y m + q (sigma(t) - b0)  with  h' = 1 + q s (1-s)
+    in [1, 1 + q/4], so h is nearly the identity when q is small and
+    Newton reaches the float32 root in two or three steps.  The bracket
+    and the guard keep it convergent for any q > 0.  NEWTON_STEPS
+    evaluations of h, the last one in `_logit_update`, unrolled: one
+    static chain inside the kernels' per-example loop.
     """
     b0 = a * y
-    # feasible b in [lo, hi]; keep strictly inside for the log (f32-safe)
-    blo = jnp.full_like(b0, 1e-6)
-    bhi = jnp.full_like(b0, 1.0 - 1e-6)
-
-    def gprime(b):
-        d = (b - b0) * y  # since b = (a+d) y and y^2 = 1
-        return y * (jnp.log(b) - jnp.log1p(-b)) + m + q * d
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = 0.5 * (lo + hi)
-        gp = gprime(mid)
-        # g' increasing in d; d increasing in b iff y > 0.  Bisect on b with
-        # the sign flip folded in: moving b by +y moves d by +1.
-        go_up = (gp * y) < 0.0
-        lo = jnp.where(go_up, mid, lo)
-        hi = jnp.where(go_up, hi, mid)
-        return lo, hi
-
-    lo, hi = jax.lax.fori_loop(0, _BISECT_ITERS, body, (blo, bhi))
-    b = 0.5 * (lo + hi)
-    return (b - b0) * y
+    c = y * m
+    t, lo, hi = _logit_bracket(c, q, b0)
+    for _ in range(NEWTON_STEPS - 1):
+        t, lo, hi, _ = _logit_step(t, lo, hi, c, q, b0)
+    return _logit_update(t, c, q, b0, y)
 
 
 RIDGE = Objective("ridge", _ridge_loss, _ridge_conj_neg, _ridge_delta,

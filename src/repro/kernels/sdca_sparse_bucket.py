@@ -273,7 +273,7 @@ def _kernel(obj: Objective, idx_s, idx_ref, val_ref, y_ref, a_ref, q_ref,
     # with the scan's own `sdca.row_sq_norms`: recomputing it per
     # tile inside the kernel lets XLA vectorize the reduction
     # differently and drifts q by 1 ulp on some rows, which the
-    # bisection amplifies — the bitwise contract dies there.
+    # Newton update carries into d — the bitwise contract dies there.
     a0 = a_ref[...].astype(jnp.float32)                     # (1, B)
     lam_n = scal_ref[0]
     sig = scal_ref[1]

@@ -45,7 +45,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import engine
 from repro.core.config import AlgoConfig, DeploymentConfig, EngineConfig
-from repro.core.objectives import LOGISTIC, Objective
+from repro.core.objectives import LOGISTIC, NEWTON_STEPS, Objective
 
 # The shard_map wrapper (check_vma off — see the note in
 # core/engine.py) lives in the engine with the streamed mesh path;
@@ -517,7 +517,9 @@ def make_streamed_epoch_mesh(scale: GLMScale, mesh, source,
 # form below is exact for this algorithm and is used for the roofline)
 # ---------------------------------------------------------------------------
 
-_BISECT_FLOPS = 40 * 12       # logistic delta: 40 bisection iters
+# logistic delta: NEWTON_STEPS evaluations of h and their guarded
+# steps, 17 FLOPs each (exp and reciprocal counted as one)
+_DELTA_FLOPS = NEWTON_STEPS * 17
 
 
 def glm_analytic(scale: GLMScale, mesh, *, streamed: bool = False) -> dict:
@@ -538,15 +540,15 @@ def glm_analytic(scale: GLMScale, mesh, *, streamed: bool = False) -> dict:
 
     if scale.kind == "dense":
         # per bucket: margins 2*d_loc*B + Gram d_loc*B^2 + v-update
-        # 2*d_loc*B + recursion B * (B axpy + bisection)
+        # 2*d_loc*B + recursion B * (B axpy + delta)
         per_bucket = (2 * d_loc * B + d_loc * B * B + 2 * d_loc * B
-                      + B * (2 * B + _BISECT_FLOPS))
+                      + B * (2 * B + _DELTA_FLOPS))
         flops = nb * per_bucket
         x_bytes = d_loc * n_local * 4
         # X streamed once per chunked pass + rotated once (read+write)
         bytes_acc = x_bytes * 3 + scale.chunks * d_loc * 4 * 2
     else:
-        per_coord = (2 * scale.nnz * 3 + _BISECT_FLOPS)
+        per_coord = (2 * scale.nnz * 3 + _DELTA_FLOPS)
         flops = n_local * per_coord
         x_bytes = n_local * scale.nnz * 8
         bytes_acc = x_bytes * 3 + n_local * scale.nnz * 4 * 2  # v gather/scatter
