@@ -31,6 +31,15 @@ Data sources accepted by the constructor, uniformly:
 Examples are PADDED (x=0, y=+1 — inert, a zero row never moves v) up
 to the multiple the chosen topology needs, so any sklearn-shaped n
 trains without manual padding; ``n_examples`` records the true count.
+
+Resident data goes on a device mesh where `DeploymentConfig.lanes_on`
+says so (pods x lanes > 1 workers, features replicated, at least as
+many devices), or where ``mesh=`` is given: each lane's rows on a
+device of its own, re-dealt by all-to-all every epoch
+(`launch.glm.make_sparse_epoch`/`make_dense_epoch`).  The rows carry
+their row ids, so `alpha` leaves the session in the caller's row order
+whatever the layout.  Everything else runs the simulator, its lanes
+stacked on one device.
 """
 from __future__ import annotations
 
@@ -68,6 +77,28 @@ def margins(v, data) -> jnp.ndarray:
                        * jnp.asarray(val), axis=1)
     return jnp.dot(jnp.asarray(data).T, jnp.asarray(v),
                    precision=jax.lax.Precision.HIGHEST)
+
+
+class _MeshEpoch:
+    """The shard_map epoch over rows resident on a mesh, called as
+    `(alpha, v, epoch)` with alpha in the current layout.  Each call
+    re-deals the rows, labels, row ids and alpha across the lanes and
+    hands the new layout back to the session, so its next epoch, gap
+    check and reads see rows and alpha that moved together."""
+
+    def __init__(self, session, fn, *, redeal_bytes: int, lane_syncs: int):
+        self._session = session
+        self._fn = jax.jit(fn)
+        self._redeal_bytes = redeal_bytes
+        self._lane_syncs = lane_syncs
+
+    def __call__(self, a, v, e):
+        s = self._session
+        *rows, a, v = self._fn(*s._layout(), a, v, e)
+        s._set_layout(rows)
+        obs.add("redeal_bytes", self._redeal_bytes)
+        obs.add("lane_syncs", self._lane_syncs)
+        return a, v
 
 
 class _ResidentEpoch:
@@ -128,7 +159,16 @@ def _check_sparse_kernel_invariant(spec: EngineConfig, idx: np.ndarray,
 
 
 class Session:
-    """Engine state + epoch control over one resolved data source."""
+    """Engine state + epoch control over one resolved data source.
+
+    Resident rows go on a ("pod","data","model") mesh, one lane a
+    device, where ``mesh=`` is given or `DeploymentConfig.lanes_on`
+    holds for the backend's device count (the mesh is then built with
+    `make_host_mesh(pod=pods, data=lanes)`); else the lanes are
+    simulated on one device.  ``mesh=`` with a streamed source drives
+    the mesh input pipeline instead.  A resident mesh takes no
+    ``journal_dir`` or fault injector.
+    """
 
     def __init__(self, data, y=None, *, objective: str | Objective | None
                  = None, lam: Optional[float] = None,
@@ -148,8 +188,10 @@ class Session:
         # pipeline (launch.glm.make_streamed_epoch_mesh / DESIGN.md
         # S16): chunks land pre-sharded via double-buffered device_put
         # instead of the stacked-sim layout.  `stream_stats` collects
-        # the last epoch's ingest-overlap metrics on that path.
+        # the last epoch's ingest-overlap metrics on that path.  On
+        # resident data it places the rows on the mesh (`_place`).
         self._mesh = mesh
+        self._rid = None              # row ids of a resident mesh layout
         self.stream_stats: dict[str, float] = {}
         self.cache = None
         self.feed = None
@@ -198,11 +240,6 @@ class Session:
             self._init_from_arrays(data, y, objective=objective, lam=lam,
                                    d=d, bucket=bucket, pad=pad,
                                    jit_step=jit_step)
-        if self._mesh is not None and self.feed is None:
-            raise ValueError(
-                "mesh= streams chunks onto the mesh, so it needs a "
-                "streamed source: pass streamed=True (arrays/registry/"
-                "cache) or a ChunkFeed")
         if self._journal is not None:
             # restart path: pick up the last committed epoch state, so
             # a re-constructed Session (new process after a crash)
@@ -309,14 +346,7 @@ class Session:
                                  lam_scaled=True)
             return
 
-        if sparse:
-            self.idx = jnp.asarray(idx)
-            self.val = jnp.asarray(val)
-        else:
-            self.X = jnp.asarray(X)
-        self.y = jnp.asarray(y)
         self.sparse = sparse
-
         dep = self.spec.deployment
         self.bplan = make_plan(self.n, self.d, force=force or 1)
         if self.bplan.bucket != algo.bucket:
@@ -328,8 +358,80 @@ class Session:
             n_buckets=self.bplan.n_buckets, pods=dep.pods,
             lanes=dep.lanes, mode=algo.partition, seed=algo.seed,
             redeal_frac=algo.redeal_frac)
-        self._init_state()
+        block = (idx, val) if sparse else (X,)
+        if self._mesh is None and dep.lanes_on(jax.device_count()):
+            from repro.launch.mesh import make_host_mesh
+            self._mesh = make_host_mesh(pod=dep.pods, data=dep.lanes)
+        if self._mesh is not None:
+            self._place(block, y)
+        else:
+            self._set_layout([jnp.asarray(x) for x in block + (y,)])
+            self._init_state()
         self._rebuild_epoch_fn()
+
+    def _place(self, block, y) -> None:
+        """Shard the rows, labels, their row ids and alpha over the
+        mesh's example axes, v replicated (`glm.resident_shardings`)."""
+        from repro.launch import glm
+        if self._journal is not None or self._faults is not None:
+            raise ValueError(
+                "a resident mesh re-deals rows every epoch, and the "
+                "journal and fault injector keep state in a fixed row "
+                "order: drop journal_dir/faults ($REPRO_FAULTS), or pass "
+                "streamed=True")
+        scale = glm.scale_for_estimator(
+            self, **({"nnz": int(block[0].shape[1])} if self.sparse
+                     else {}))
+        workers = glm._worker_count(self._mesh, scale)
+        if workers != self.spec.workers:
+            raise ValueError(
+                f"the mesh {dict(self._mesh.shape)} has {workers} "
+                f"workers, the config pods x lanes = {self.spec.workers}")
+        rows_sh, ex_sh, v_sh = glm.resident_shardings(scale, self._mesh)
+        host = block + (y, np.arange(self.n, dtype=np.int32))
+        with obs.span("mesh.place"):
+            obs.add("h2d_bytes", sum(x.nbytes for x in host))
+            self._set_layout([jax.device_put(x, sh) for x, sh in zip(
+                host, rows_sh + (ex_sh, ex_sh))])
+        # alpha[i] belongs to row rid[i]: one gather puts row-ordered
+        # alpha into the layout, one scatter takes it back
+        self._to_layout = jax.jit(lambda a, rid: a[rid],
+                                  out_shardings=ex_sh)
+        self._to_rows = jax.jit(
+            lambda a, rid: jnp.zeros_like(a).at[rid].set(
+                a, unique_indices=True), out_shardings=ex_sh)
+        self._v_sharding = v_sh
+        self._alpha = jnp.zeros(self.n, jnp.float32, device=ex_sh)
+        self.v = jnp.zeros(self.d, jnp.float32, device=v_sh)
+        self.epochs_done = 0
+
+    def _layout(self) -> tuple:
+        """The resident arrays in their current order: the rows, y and,
+        on a mesh, the row ids."""
+        rows = (self.idx, self.val) if self.sparse else (self.X,)
+        return rows + (self.y,) + (() if self._rid is None
+                                   else (self._rid,))
+
+    def _set_layout(self, arrays) -> None:
+        if self.sparse:
+            self.idx, self.val, self.y, *rid = arrays
+        else:
+            self.X, self.y, *rid = arrays
+        if rid:
+            self._rid, = rid
+
+    @property
+    def alpha(self):
+        """The dual vector, in the caller's row order on every route."""
+        if self._rid is None:
+            return self._alpha
+        with obs.span("mesh.order"):
+            return self._to_rows(self._alpha, self._rid)
+
+    @alpha.setter
+    def alpha(self, a) -> None:
+        self._alpha = a if self._rid is None else self._to_layout(
+            np.asarray(a), self._rid)
 
     def _init_from_cache(self, cache, *, objective, lam, streamed,
                          jit_step) -> None:
@@ -455,7 +557,18 @@ class Session:
         """(Re)compile the epoch program from the current spec/damp —
         called at construction and by health remedies (solver reroute,
         damping) that change how an epoch runs."""
-        if self.feed is not None and self._mesh is not None:
+        if self._rid is not None:
+            from repro.launch import glm
+            scale = glm.scale_for_estimator(self)
+            make = (glm.make_sparse_epoch if self.sparse
+                    else glm.make_dense_epoch)
+            self._epoch_fn = _MeshEpoch(
+                self, make(scale, self._mesh, obj=self.obj, row_ids=True,
+                           damp=self._damp),
+                redeal_bytes=glm.redeal_bytes(scale, self._mesh,
+                                              row_ids=True),
+                lane_syncs=scale.chunks)
+        elif self.feed is not None and self._mesh is not None:
             from repro.launch import glm
             dep = self.spec.deployment
             kw: dict[str, Any] = {}
@@ -520,14 +633,14 @@ class Session:
                     self._faults.maybe_kernel_fail(e)
             v_prev = self.v
             with obs.span("epoch.program", epoch=e):
-                self.alpha, self.v = self._epoch_fn(
-                    self.alpha, self.v, jnp.int32(e))
+                self._alpha, self.v = self._epoch_fn(
+                    self._alpha, self.v, jnp.int32(e))
             if self._faults is not None and self._faults.nan_epoch(e):
                 self.v = self.v * jnp.float32(float("nan"))
             self.epochs_done += 1
             if self._journal is not None:
                 with obs.span("journal", epoch=e):
-                    self._journal.commit_epoch(self.alpha, self.v,
+                    self._journal.commit_epoch(self._alpha, self.v,
                                                self.epochs_done)
             rel = obs.read(
                 "rel_change",
@@ -647,7 +760,7 @@ class Session:
         return FitResult(
             epochs=self.epochs_done, converged=converged,
             diverged=diverged, v=obs.read("result", self.v, epoch=e),
-            alpha=obs.read("result", self.alpha, epoch=e),
+            alpha=obs.read("result", lambda: self.alpha, epoch=e),
             history=history, wall_time=time.perf_counter() - t0)
 
     # -- diagnostics -------------------------------------------------------
@@ -717,12 +830,14 @@ class Session:
             if self.streamed:
                 p, dv = self._streamed_primal_dual()
                 return p - dv
+            # rows, labels and alpha share one layout: the sums below
+            # hold on a re-dealt mesh layout as on the caller's order
             if self.sparse:
-                dval = objectives.dual_value(self.obj, self.alpha, self.v,
+                dval = objectives.dual_value(self.obj, self._alpha, self.v,
                                              self.y, self.lam)
                 return self.primal() - obs.read("dual", dval, epoch=e)
             return obs.read("gap", lambda: objectives.duality_gap(
-                self.obj, self.alpha, self.v, self.X, self.y, self.lam),
+                self.obj, self._alpha, self.v, self.X, self.y, self.lam),
                 epoch=e)
 
     # -- checkpoint/restart ------------------------------------------------
@@ -733,12 +848,18 @@ class Session:
                 "epoch": np.int64(self.epochs_done)}
 
     def load_state_dict(self, st: dict[str, Any]) -> None:
-        """Restore training state produced by `state_dict`."""
+        """Restore training state produced by `state_dict`: alpha in
+        row order, put into the current layout on a mesh."""
         with obs.span("state.load"):
             obs.add("h2d_bytes", sum(st[k].nbytes for k in ("alpha", "v")
                                      if isinstance(st[k], np.ndarray)))
-            self.alpha = jnp.asarray(st["alpha"])
-            self.v = jnp.asarray(st["v"])
+            if self._rid is None:
+                self.alpha = jnp.asarray(st["alpha"])
+                self.v = jnp.asarray(st["v"])
+            else:
+                with obs.span("mesh.place"):
+                    self.alpha = st["alpha"]
+                    self.v = jax.device_put(st["v"], self._v_sharding)
         self.epochs_done = int(st["epoch"])
 
     def save(self, path, *, meta: Optional[dict] = None) -> None:

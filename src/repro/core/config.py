@@ -46,7 +46,13 @@ class AlgoConfig:
 
 @dataclasses.dataclass(frozen=True)
 class DeploymentConfig:
-    """Where the solver runs: worker topology + wire/compute policies."""
+    """Where the solver runs: worker topology + wire/compute policies.
+
+    The pods x lanes workers go on devices of their own where
+    `lanes_on` says so: a resident `Session` then builds the
+    ("pod","data","model") mesh itself and runs the shard_map epoch
+    (launch/glm.py).  Otherwise they are virtual workers stacked on one
+    device (the simulator)."""
     pods: int = 1                   # NUMA nodes -> TPU pods (static outer)
     lanes: int = 1                  # threads -> chips (dynamic inner)
     feature_shard: bool = False     # dense TP: shard d over 'model'
@@ -56,6 +62,12 @@ class DeploymentConfig:
     # and mesh backends produce bitwise-identical results.  Costs some
     # throughput; off by default.
     deterministic: bool = False
+
+    def lanes_on(self, devices: int) -> bool:
+        """True where each of the pods x lanes workers (more than one)
+        gets one of `devices` to itself, with features replicated: the
+        one rule that puts lanes on chips."""
+        return not self.feature_shard and 1 < self.pods * self.lanes <= devices
 
 
 @dataclasses.dataclass(frozen=True)

@@ -83,10 +83,19 @@ def shard_map(f, mesh, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 
 
+def _row_ids(rid):
+    """A block's optional row ids (*w, n_local) as re-deal entries."""
+    return () if rid is None else ((rid, -1),)
+
+
 @dataclasses.dataclass(frozen=True)
 class DenseBlock:
-    """Dense worker-local examples: X (*w, d_shard, n_local)."""
+    """Dense worker-local examples: X (*w, d_shard, n_local).  `rid`,
+    where given, holds each example's row in the caller's data and is
+    re-dealt with it, so a re-dealt layout can be read back in row
+    order."""
     X: Array
+    rid: Optional[Array] = None
 
     @property
     def n_local(self) -> int:
@@ -96,17 +105,19 @@ class DenseBlock:
         return jnp.take_along_axis(self.X, cols[..., None, :], axis=-1)
 
     def arrs(self):
-        return ((self.X, -1),)
+        return ((self.X, -1),) + _row_ids(self.rid)
 
     def rebuild(self, arrs) -> "DenseBlock":
-        return DenseBlock(arrs[0])
+        return DenseBlock(*arrs)
 
 
 @dataclasses.dataclass(frozen=True)
 class SparseBlock:
-    """Padded-CSR worker-local examples: idx/val (*w, n_local, nnz)."""
+    """Padded-CSR worker-local examples: idx/val (*w, n_local, nnz),
+    with optional row ids as in `DenseBlock`."""
     idx: Array
     val: Array
+    rid: Optional[Array] = None
 
     @property
     def n_local(self) -> int:
@@ -117,10 +128,10 @@ class SparseBlock:
                 jnp.take_along_axis(self.val, cols[..., :, None], axis=-2))
 
     def arrs(self):
-        return ((self.idx, -2), (self.val, -2))
+        return ((self.idx, -2), (self.val, -2)) + _row_ids(self.rid)
 
     def rebuild(self, arrs) -> "SparseBlock":
-        return SparseBlock(arrs[0], arrs[1])
+        return SparseBlock(*arrs)
 
 
 Block = Union[DenseBlock, SparseBlock]
@@ -834,6 +845,7 @@ def sharded_epoch(
     model_axis: Optional[str] = None,
     model_lanes: Optional[int] = None,
     interpret: Optional[bool] = None,
+    dv_scale_mul: float = 1.0,
 ) -> tuple[Block, Array, Array, Array]:
     """Epoch over a *physically partitioned* workload (the distributed
     layout): partition != 'static' re-deals buckets across lanes, the
@@ -842,7 +854,8 @@ def sharded_epoch(
     equivalence test runs on both.  `model_axis` + `model_lanes` on a
     sparse block select the feature-sharded solver layout (the model
     axis carries v slices and joins the sync axes instead of the
-    example axes — launch/glm.py wires both ends)."""
+    example axes — launch/glm.py wires both ends).  `dv_scale_mul` is
+    the health guard's damping, as in `sim_epoch_dense`."""
     algo = spec.algo
     lam_n = lam * n_total
     sig = spec.sigma_prime(workers)
@@ -851,7 +864,8 @@ def sharded_epoch(
         sparse=isinstance(block, SparseBlock), model_axis=model_axis,
         model_lanes=model_lanes, interpret=interpret,
         source="resident shard arrays")
-    dv_scale = (1.0 / workers if algo.aggregation == "averaging" else 1.0)
+    dv_scale = (1.0 / workers if algo.aggregation == "averaging"
+                else 1.0) * dv_scale_mul
     return run_epoch(
         coll, solver, algo, block, y, a, v, epoch,
         redeal=(algo.partition != "static"), visit_shuffle=True,

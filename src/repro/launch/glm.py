@@ -207,8 +207,12 @@ def scale_for_estimator(est, **overrides) -> GLMScale:
 
 
 def estimator_epoch(est, mesh, **overrides):
-    """Lower an `repro.api` estimator onto a device mesh.
+    """Lower an `repro.api` estimator onto a device mesh, as a program
+    over global arrays the caller builds and feeds.
 
+    The normal path is `Session` itself: with `lanes` workers and as
+    many devices, or with ``mesh=``, it places its rows on the mesh and
+    runs this same program every epoch (`DeploymentConfig.lanes_on`).
     Returns ``(epoch_fn, scale)``: `epoch_fn` is the shard_map'd epoch
     program over global arrays (same signature as `make_dense_epoch` /
     `make_sparse_epoch` products; jit/donate and feed it
@@ -281,37 +285,71 @@ def _collectives(mesh, scale: GLMScale) -> engine.MeshCollectives:
         compress_pod=scale.compress_pod)
 
 
-def make_dense_epoch(scale: GLMScale, mesh, obj: Objective = LOGISTIC):
-    """-> jit-ready epoch fn over global arrays (X, y, alpha, v, epoch)."""
+def _specs(scale: GLMScale, mesh):
+    """-> (block specs, example spec, v spec): how a resident epoch's
+    rows, its per-example vectors (y, alpha, row ids) and v lie on the
+    mesh."""
     ex_axes, _, _, tp = _axes(mesh, scale)
+    if scale.kind == "sparse":
+        block = (P(ex_axes, None),) * 2
+    else:
+        block = (P("model" if tp else None, ex_axes),)
+    return block, P(ex_axes), P("model") if tp else P(None)
+
+
+def _epoch_specs(scale: GLMScale, mesh, row_ids: bool):
+    """shard_map (in_specs, out_specs) of an epoch over (*block, y,
+    [rid,] alpha, v, epoch) -> (*block, y, [rid,] alpha, v)."""
+    block, e_spec, v_spec = _specs(scale, mesh)
+    out = block + (e_spec,) * (3 if row_ids else 2) + (v_spec,)
+    return out + (P(),), out
+
+
+def resident_shardings(scale: GLMScale, mesh):
+    """-> (block shardings, example sharding, v sharding): where
+    `Session` places resident rows for the epoch programs below, so
+    each epoch's outputs lie exactly as its inputs did."""
+    block, e_spec, v_spec = _specs(scale, mesh)
+    return (tuple(NamedSharding(mesh, b) for b in block),
+            NamedSharding(mesh, e_spec), NamedSharding(mesh, v_spec))
+
+
+def make_dense_epoch(scale: GLMScale, mesh, obj: Objective = LOGISTIC, *,
+                     row_ids: bool = False, damp: float = 1.0):
+    """-> jit-ready epoch fn over global arrays (X, y, alpha, v, epoch).
+
+    ``row_ids=True`` adds each example's row id after y, re-dealt with
+    the example and returned in the same place (what `Session` reads
+    alpha back in row order by); ``damp`` is the health guard's
+    dv_scale multiplier."""
+    _, _, _, tp = _axes(mesh, scale)
     W = _worker_count(mesh, scale)
     spec = scale.engine_config(mesh)
     coll = _collectives(mesh, scale)
     model_axis = "model" if tp else None
 
-    def epoch_fn(X, y, a, v, epoch):
+    def epoch_fn(X, y, *rest):
         # X: (d_loc, n_local) f32; y/a: (n_local,); v: (d_loc,)
+        *rid, a, v, epoch = rest
         blk, y, a, v = engine.sharded_epoch(
-            obj, spec, coll, engine.DenseBlock(X), y, a, v, epoch,
+            obj, spec, coll, engine.DenseBlock(X, *rid), y, a, v, epoch,
             lam=scale.lam, n_total=scale.n, workers=W,
-            model_axis=model_axis)
-        return blk.X, y, a, v
+            model_axis=model_axis, dv_scale_mul=damp)
+        return (blk.X, y) + ((blk.rid,) if row_ids else ()) + (a, v)
 
-    x_spec = P("model" if tp else None, ex_axes)
-    e_spec = P(ex_axes)
-    v_spec = P("model") if tp else P(None)
-    return shard_map(
-        epoch_fn, mesh,
-        in_specs=(x_spec, e_spec, e_spec, v_spec, P()),
-        out_specs=(x_spec, e_spec, e_spec, v_spec))
+    in_specs, out_specs = _epoch_specs(scale, mesh, row_ids)
+    return shard_map(epoch_fn, mesh, in_specs=in_specs,
+                     out_specs=out_specs)
 
 
 def make_sparse_epoch(scale: GLMScale, mesh, obj: Objective = LOGISTIC,
-                      *, interpret: bool | None = None):
-    """`interpret` forces the Pallas kernels' interpret mode (tests
-    drive TPU-targeted solver selection on CPU hosts with it); None =
-    backend default."""
-    ex_axes, _, _, _ = _axes(mesh, scale)
+                      *, interpret: bool | None = None,
+                      row_ids: bool = False, damp: float = 1.0):
+    """-> jit-ready epoch fn over global arrays (idx, val, y, alpha, v,
+    epoch); ``row_ids`` and ``damp`` as in `make_dense_epoch`.
+    `interpret` forces the Pallas kernels' interpret mode (tests drive
+    TPU-targeted solver selection on CPU hosts with it); None = backend
+    default."""
     W = _worker_count(mesh, scale)
     spec = scale.engine_config(mesh)
     coll = _collectives(mesh, scale)
@@ -319,47 +357,59 @@ def make_sparse_epoch(scale: GLMScale, mesh, obj: Objective = LOGISTIC,
     model_axis = "model" if sparse_tp else None
     model_lanes = mesh.shape["model"] if sparse_tp else None
 
-    def epoch_fn(idx, val, y, a, v, epoch):
+    def epoch_fn(idx, val, y, *rest):
         # idx/val: (n_local, nnz); v: (d,) replicated at the XLA level
         # even when feature-sharded — each lane's solver writes only
         # its own d/M slice and the model-axis sync reassembles them
+        *rid, a, v, epoch = rest
         blk, y, a, v = engine.sharded_epoch(
-            obj, spec, coll, engine.SparseBlock(idx, val), y, a, v,
+            obj, spec, coll, engine.SparseBlock(idx, val, *rid), y, a, v,
             epoch, lam=scale.lam, n_total=scale.n, workers=W,
             model_axis=model_axis, model_lanes=model_lanes,
-            interpret=interpret)
-        return blk.idx, blk.val, y, a, v
+            interpret=interpret, dv_scale_mul=damp)
+        return (blk.idx, blk.val, y) + ((blk.rid,) if row_ids else ()) \
+            + (a, v)
 
-    r_spec = P(ex_axes, None)
-    e_spec = P(ex_axes)
-    return shard_map(
-        epoch_fn, mesh,
-        in_specs=(r_spec, r_spec, e_spec, e_spec, P(None), P()),
-        out_specs=(r_spec, r_spec, e_spec, e_spec, P(None)))
+    in_specs, out_specs = _epoch_specs(scale, mesh, row_ids)
+    return shard_map(epoch_fn, mesh, in_specs=in_specs,
+                     out_specs=out_specs)
+
+
+def redeal_bytes(scale: GLMScale, mesh, *, row_ids: bool = False) -> int:
+    """Bytes an epoch's all-to-all re-deal sends between devices, summed
+    over the mesh, from the shapes: each device splits its exchanged
+    buckets over the `data` lanes and keeps one share; every array of an
+    example moves (its row, y, alpha, and the row id where carried)."""
+    K = mesh.shape.get("data", 1)
+    if scale.partition == "static" or K <= 1 or scale.redeal_frac <= 0:
+        return 0
+    _, _, _, tp = _axes(mesh, scale)
+    nb_local = scale.n // _worker_count(mesh, scale) // scale.bucket
+    exch = max(int(nb_local * scale.redeal_frac) // K * K, K)
+    if scale.kind == "sparse":
+        row = scale.nnz * 8
+    else:
+        row = (scale.d // mesh.shape["model"] if tp else scale.d) * 4
+    row += 4 * (3 if row_ids else 2)
+    return mesh.size * exch * scale.bucket * row * (K - 1) // K
 
 
 def glm_input_specs(scale: GLMScale, mesh):
-    ex_axes, _, _, tp = _axes(mesh, scale)
+    block, e_spec, v_spec = _specs(scale, mesh)
 
     def sds(shape, dtype, spec):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    e_spec = P(ex_axes)
     if scale.kind == "sparse":
-        return (sds((scale.n, scale.nnz), jnp.int32, P(ex_axes, None)),
-                sds((scale.n, scale.nnz), jnp.float32, P(ex_axes, None)),
-                sds((scale.n,), jnp.float32, e_spec),
-                sds((scale.n,), jnp.float32, e_spec),
-                sds((scale.d,), jnp.float32, P(None)),
-                jax.ShapeDtypeStruct((), jnp.int32))
-    x_spec = P("model" if tp else None, ex_axes)
-    v_spec = P("model") if tp else P(None)
-    return (sds((scale.d, scale.n), jnp.float32, x_spec),
-            sds((scale.n,), jnp.float32, e_spec),
-            sds((scale.n,), jnp.float32, e_spec),
-            sds((scale.d,), jnp.float32, v_spec),
-            jax.ShapeDtypeStruct((), jnp.int32))
+        rows = (sds((scale.n, scale.nnz), jnp.int32, block[0]),
+                sds((scale.n, scale.nnz), jnp.float32, block[1]))
+    else:
+        rows = (sds((scale.d, scale.n), jnp.float32, block[0]),)
+    return rows + (sds((scale.n,), jnp.float32, e_spec),
+                   sds((scale.n,), jnp.float32, e_spec),
+                   sds((scale.d,), jnp.float32, v_spec),
+                   jax.ShapeDtypeStruct((), jnp.int32))
 
 
 def lower_glm(arch: str, mesh):

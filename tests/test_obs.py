@@ -2,6 +2,12 @@
 counts a `fit` leaves, the span tree a profiler trace holds, and the
 span a compile is put down to."""
 import glob
+import json
+import os
+import pathlib
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +142,77 @@ def test_compiles_are_keyed_by_the_span_that_compiled():
     assert got["compiles.repro.probe.inner"] == 1
     assert not any(k.startswith("compiles.") and "probe" not in k
                    for k in got), got
+
+
+# -- a resident mesh: placement, re-deal, lane sums and the row order ------
+
+MESH = """
+    import glob, json, sys
+    import jax, numpy as np
+    from jax.profiler import ProfileData
+    from repro import obs
+    from repro.api import Session
+    from repro.core import EngineConfig
+    from repro.data import make_sparse_classification
+
+    (idx, val), y, d = make_sparse_classification(n=1024, d=64, nnz=8,
+                                                  seed=0)
+    cfg = EngineConfig.make(lanes=4, bucket=8, chunks=2,
+                            partition="alltoall")
+    out = {}
+    obs.reset()
+    ses = Session(((idx, val), y), d=d, lam=1e-2, cfg=cfg)
+    out["placed"] = obs.counters()
+    ses.fit(max_epochs=1, tol=0.0)        # compile outside the trace
+    ses.load_state_dict(ses.state_dict())
+    obs.reset()
+    logdir = sys.argv[1]
+    with jax.profiler.trace(logdir):
+        ses.fit(max_epochs=2, tol=0.0)
+        ses.load_state_dict(ses.state_dict())
+    out["fit"] = obs.counters()
+    path = glob.glob(logdir + "/**/*.xplane.pb", recursive=True)[0]
+    out["spans"] = [
+        (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name, line.name)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:") for line in plane.lines
+        for ev in line.events if ev.name.startswith("repro.")]
+    print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh_obs(tmp_path_factory):
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(repo / "src"))
+    logdir = str(tmp_path_factory.mktemp("mesh-trace"))
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(MESH), logdir],
+                       capture_output=True, text=True, env=env, timeout=600)
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT ")]
+    assert line, r.stdout + r.stderr
+    return json.loads(line[-1][len("RESULT "):])
+
+
+def test_mesh_counts_placement_redeal_bytes_and_lane_syncs(mesh_obs):
+    # placement: idx and val (1024 x 8 x 4 B each), y and the row ids
+    assert mesh_obs["placed"]["h2d_bytes"] == 2 * 1024 * 8 * 4 + 2 * 1024 * 4
+    got = mesh_obs["fit"]
+    # a lane holds 32 buckets of 8 rows and deals 3/4 of them away; a
+    # row is 8 ids, 8 values, y, alpha and its row id: 76 B
+    assert got["redeal_bytes"] == 2 * 4 * 32 * 8 * 76 * 3 // 4
+    assert got["lane_syncs"] == 2 * 2          # chunks an epoch
+    # reloaded row-ordered state: alpha (4 KB) and v (256 B)
+    assert got["h2d_bytes"] == 1024 * 4 + 64 * 4
+    assert not any(k.startswith("compiles.") for k in got), got
+
+
+def test_mesh_spans_place_and_order(mesh_obs):
+    spans = [tuple(s) for s in mesh_obs["spans"]]
+    parents = {}
+    for s in spans:
+        parents.setdefault(s[2], []).append(_parent(spans, s))
+    # the fit's alpha copy, then state_dict's, outside any other span
+    assert parents["repro.mesh.order"] == ["repro.read.result", None]
+    assert parents["repro.mesh.place"] == ["repro.state.load"]

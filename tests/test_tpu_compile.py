@@ -8,6 +8,7 @@ the TPU library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ NB = 16                       # buckets per compiled sub-epoch
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -34,8 +35,13 @@ def one_chip():
     # keep them out of any persistent compilation cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(sharding, shape, dtype=jnp.float32):
@@ -132,3 +138,28 @@ def test_sparse_vmem_estimate_is_enough(one_chip, vmem_limit):
     VMEM its estimator counts (the resident 4 MB v included)."""
     vmem_limit(ssb.vmem_bytes_estimate(16, 40, 1_000_000))
     _compile(*_sparse(one_chip, 1_000_000, 40, 16))
+
+
+def test_resident_mesh_epoch_compiles_for_four_chips(topo):
+    """The epoch `Session` runs with four lanes on a v5e:2x2 host, at
+    criteo's width (d = 1,000,000, nnz = 40): the sparse kernel on each
+    chip, one all-to-all for each re-dealt array (idx, val, y, alpha,
+    row ids) and one lane-sum all-reduce a chunk."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    from repro.launch import glm
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1),
+                ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
+    n, d = 8192, 1_000_000
+    sc = glm.GLMScale("criteo-dp4", "sparse", n=n, d=d, nnz=40, bucket=8,
+                      chunks=2, compress_pod=False, local_solver="pallas")
+    rows, ex, v_sh = glm.resident_shardings(sc, mesh)
+    args = (_sds(rows[0], (n, 40), jnp.int32), _sds(rows[1], (n, 40)),
+            _sds(ex, (n,)), _sds(ex, (n,), jnp.int32), _sds(ex, (n,)),
+            _sds(v_sh, (d,)), jax.ShapeDtypeStruct((), jnp.int32))
+    text = _compile(glm.make_sparse_epoch(sc, mesh, LOGISTIC,
+                                          interpret=False, row_ids=True),
+                    *args)
+    assert len(re.findall(r"= \S+ all-to-all\(", text)) == 5
+    assert len(re.findall(r"= \S+ all-reduce\(", text)) == 2
