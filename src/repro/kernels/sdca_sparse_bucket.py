@@ -20,16 +20,25 @@ cache-resident shared vector maps onto VMEM:
     bucket-local working set W (B, nnz) (VMEM scratch) at bucket entry;
   * the in-bucket recursion runs on VMEM-resident state only: per
     coordinate one margin, one scalar dual update, one update row u,
-    and an nnz-step loop that adds u's entries into every working-set
-    entry that aliases the same feature (a (B, nnz) compare/select per
-    step — later rows see the v the scan would show them);
-  * v is written back once per bucket (one scatter pass in visiting
-    order) instead of once per coordinate.
+    and an nnz-step pass that adds u's entries into every working-set
+    entry that aliases the same feature (a (B, nnz) compare/add/select
+    per step on W held in registers — later rows see the v the scan
+    would show them);
+  * after the recursion W[i, k] IS the bucket's final v at feature
+    idx[i, k], so v is written back once per bucket by plain stores of
+    W's entries (no load, no add, no order) instead of once per
+    coordinate.
+
+The three nnz loops (gather, alias, write-back) run as straight-line
+code up to `UNROLL_NNZ` entries a row and as rolled loops of
+`ENTRY_BLOCK` unrolled entries above it (`entry_loop_form`): a rolled
+loop of one entry a step waits on each step's cross-lane reduction, so
+the entries must be independent instructions the scheduler can overlap.
 
 Mosaic has no dynamic slicing of VALUES, so every per-coordinate read
 goes through a ref: rows via `pl.ds`, scalars from SMEM, a lane of a
-row by an iota-mask select (`_lane`), and writes into a row by the
-same mask (`jnp.where`).
+row by an iota-mask select (`_lane`), and writes into a row by a
+lane-masked store (`_put_v`).
 
 Bit-equivalence contract: for the same visiting order the kernel is
 BITWISE-identical to `sparse_local_subepoch` (pinned by interpret-mode
@@ -47,6 +56,12 @@ away:
     zero-valued duplicates add exact zeros on both paths).  Real
     svmlight/CSR data satisfies this by construction;
     `data/formats.zero_duplicates` enforces it for synthetic data.
+
+The write-back stores W's bits where the scan adds into v: a touched
+feature's W entries start from v's value there and receive every add
+the scan makes to it, in its order, so they hold the scan's final v.
+Only a zero's sign may differ (`_lane` reads -0.0 as +0.0 and never
+yields -0.0 for an add), and v_final - v0 erases it.
 
 Grid is 1-D over buckets with "arbitrary" dimension semantics: buckets
 are processed IN ORDER (sequential SDCA semantics).
@@ -71,8 +86,8 @@ exchange:
     the summation order and breaks the bitwise-vs-scan contract;
   * `_sharded_kernel`: run the SAME in-bucket recursion
     (`_bucket_recursion`, shared code) on the assembled W — every lane
-    redundantly — then scatter only the owned entries back into the
-    slice, in visiting order.
+    redundantly — then write back only the owned entries into the
+    slice.
 
 One exchange (M*B*nnz f32) per bucket is the whole model-axis wire
 cost, amortized over B coordinates — the bucket optimization's payoff
@@ -137,13 +152,13 @@ def vmem_bytes_estimate(B: int, nnz: int, d: int) -> int:
 
     Counts the one resident copy of v, the double-buffered idx(int32)
     and val(f32) tiles, the double-buffered (1, B) y/alpha/q/alpha-out
-    rows, and the W/U working-set scratch.  Shared with
+    rows, and the W working-set scratch.  Shared with
     `ops.sparse_solver_plan` so the "auto" path can pre-check static
     shapes and fall back instead of raising.
     """
     tiles = 2 * 2 * _tile_bytes(B, nnz)
     rows = 4 * 2 * _tile_bytes(1, B)
-    work = 2 * _tile_bytes(B, nnz)
+    work = _tile_bytes(B, nnz)
     return v_bytes(d) + tiles + rows + work
 
 
@@ -152,9 +167,10 @@ def vmem_bytes_estimate_sharded(B: int, nnz: int, d_loc: int) -> int:
 
     The update kernel dominates: the resident v SLICE, one (not
     double-buffered — one bucket per call) idx/val tile pair, the
-    exchanged working set W, the y/alpha/q/alpha-out rows and the U
-    scratch.  Shared with `ops.sparse_solver_plan` so the dispatcher
-    can pre-check the sharded route on static shapes.
+    exchanged working set W, the y/alpha/q/alpha-out rows and the W
+    scratch the recursion updates.  Shared with
+    `ops.sparse_solver_plan` so the dispatcher can pre-check the
+    sharded route on static shapes.
     """
     tiles = 2 * _tile_bytes(B, nnz)
     wexch = _tile_bytes(B, nnz)
@@ -178,14 +194,49 @@ def _read_v(v_ref, p, lane_ok=True):
     return _lane(row, jnp.where(lane_ok, p & (LANES - 1), -1))
 
 
-def _add_v(v_ref, p, u, lane_ok=True):
-    """v[p] += u (u a (1, 1) value); a no-op where `lane_ok` is False —
-    the row is stored back bit-for-bit."""
-    r = pl.ds(p >> 7, 1)
-    row = v_ref[r, :]
-    ids = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+def _put_v(v_ref, p, x, lane_ok=True):
+    """v[p] = x (x a (1, 1) value) by a lane-masked store: no load, no
+    add; nothing is stored where `lane_ok` is False."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
     hit = ids == jnp.where(lane_ok, p & (LANES - 1), -1)
-    v_ref[r, :] = jnp.where(hit, row + u, row)
+    pltpu.store(v_ref.at[pl.ds(p >> 7, 1), :],
+                jnp.broadcast_to(x, (1, LANES)), mask=hit)
+
+
+#: Rows up to this many entries run their nnz loops fully unrolled;
+#: wider rows run a rolled loop over blocks of `ENTRY_BLOCK` unrolled
+#: entries, which bounds the code size (webspam's 3,728-wide rows).
+UNROLL_NNZ = 128
+
+#: Entries a block of the rolled form; nnz % 8 == 0 is the tile contract.
+ENTRY_BLOCK = 8
+
+
+def entry_loop_form(nnz: int) -> tuple[int, int]:
+    """(blocks, entries a block) of the nnz loops for a static nnz:
+    (1, nnz), one straight-line block, up to `UNROLL_NNZ`, else
+    (nnz / ENTRY_BLOCK, ENTRY_BLOCK)."""
+    if nnz <= UNROLL_NNZ:
+        return 1, nnz
+    return nnz // ENTRY_BLOCK, ENTRY_BLOCK
+
+
+def _for_entries(nnz: int, body, carry):
+    """carry = body(k, carry) for k = 0 .. nnz-1, in order, in the form
+    `entry_loop_form` picks.  Unrolled entries are independent
+    instructions the scheduler can overlap wherever `body` carries no
+    dependence through `carry`.  `fori_loop(..., unroll=True)` traces
+    `body` once and unrolls it in the lowering, so a straight-line row
+    costs no more Python tracing than a rolled one."""
+    blocks, width = entry_loop_form(nnz)
+    if blocks == 1:
+        return jax.lax.fori_loop(0, nnz, body, carry, unroll=True)
+
+    def block(b, c):
+        return jax.lax.fori_loop(
+            0, width, lambda j, cj: body(b * width + j, cj), c, unroll=True)
+
+    return jax.lax.fori_loop(0, blocks, block, carry)
 
 
 def _gather_rows(idx_s, w_ref, read):
@@ -194,6 +245,8 @@ def _gather_rows(idx_s, w_ref, read):
     Shared by the replicated kernel (read = v lookup) and the sharded
     gather kernel (read = masked slice lookup): the loop structure must
     stay identical so both produce the same W bits for owned entries.
+    A row's nnz reads are independent; only the select into the row
+    chains them.
     """
     B, nnz = w_ref.shape
     ids = jax.lax.broadcasted_iota(jnp.int32, (1, nnz), 1)
@@ -202,24 +255,27 @@ def _gather_rows(idx_s, w_ref, read):
         def entry(k, w):
             return jnp.where(ids == k, read(idx_s[i, k]), w)
 
-        w_ref[pl.ds(i, 1), :] = jax.lax.fori_loop(
-            0, nnz, entry, jnp.zeros((1, nnz), jnp.float32))
+        w_ref[pl.ds(i, 1), :] = _for_entries(
+            nnz, entry, jnp.zeros((1, nnz), jnp.float32))
         return carry
 
     jax.lax.fori_loop(0, B, row, 0)
 
 
 def _bucket_recursion(obj: Objective, idx_s, idx, val_ref, y, a0, qrow,
-                      lam_n, sig, w_ref, u_ref):
+                      lam_n, sig, w_ref):
     """The in-bucket delta recursion on the gathered working set in
-    `w_ref`; writes the per-coordinate update rows into `u_ref` and
-    returns the (1, B) alpha deltas.
+    `w_ref`; returns the (1, B) alpha deltas and leaves in `w_ref` the
+    bucket's final v at every entry's feature.
 
     Shared VERBATIM by the replicated and sharded kernels — the sharded
-    path's bitwise claim is exactly "same W bits in, same U bits out".
+    path's bitwise claim is exactly "same W bits in, same W bits out".
     After coordinate i, every working-set entry that aliases a feature
     i touched receives the SAME u-element the scan scatter-adds into v,
-    in the scan's order, so later margins stay bit-equal.
+    in the scan's order, so later margins stay bit-equal.  W rides in
+    registers through a row's alias pass (one load, one store); the
+    lane extractions of u do not depend on W, so they overlap and only
+    the compare/add/select on W is serial.
     """
     B, nnz = w_ref.shape
     lanes_b = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
@@ -231,38 +287,41 @@ def _bucket_recursion(obj: Objective, idx_s, idx, val_ref, y, a0, qrow,
                       sig * _lane(qrow, i) / lam_n)
         # the scan's update row, computed once with its association
         u = (sig * d / lam_n) * vi
-        u_ref[pl.ds(i, 1), :] = u
 
-        def alias(k, carry):
-            W = w_ref[...]
-            w_ref[...] = jnp.where(idx == idx_s[i, k], W + _lane(u, k), W)
-            return carry
+        def alias(k, W):
+            return jnp.where(idx == idx_s[i, k], W + _lane(u, k), W)
 
-        jax.lax.fori_loop(0, nnz, alias, 0)
+        w_ref[...] = _for_entries(nnz, alias, w_ref[...])
         return jnp.where(lanes_b == i, d, deltas)
 
     return jax.lax.fori_loop(0, B, body, jnp.zeros((1, B), jnp.float32))
 
 
-def _scatter_rows(idx_s, u_ref, add):
-    """add(idx[i, k], U[i, k]) over the tile, rows in visiting order so
-    shared features accumulate in the scan's sequence."""
-    B, nnz = u_ref.shape
+def _write_back(idx_s, w_ref, put):
+    """put(idx[i, k], W[i, k]) over the tile.
+
+    After the recursion W[i, k] holds, bit for bit, the final v at
+    feature idx[i, k]: the gather read v there and the alias passes
+    added every u-element the scan adds to it, in the scan's order.
+    Entries that alias one feature hold identical bits, so the puts
+    need no add and no order.
+    """
+    B, nnz = w_ref.shape
 
     def row(i, carry):
-        u = u_ref[pl.ds(i, 1), :]
+        w = w_ref[pl.ds(i, 1), :]
 
         def entry(k, c):
-            add(idx_s[i, k], _lane(u, k))
+            put(idx_s[i, k], _lane(w, k))
             return c
 
-        return jax.lax.fori_loop(0, nnz, entry, carry)
+        return _for_entries(nnz, entry, carry)
 
     jax.lax.fori_loop(0, B, row, 0)
 
 
 def _kernel(obj: Objective, idx_s, idx_ref, val_ref, y_ref, a_ref, q_ref,
-            scal_ref, v_hbm, aout_ref, v_ref, w_ref, u_ref):
+            scal_ref, v_hbm, aout_ref, v_ref, w_ref):
     """Body for one bucket (one grid step) — replicated v."""
     # v lives in the aliased output block; copy it in from HBM once
     @pl.when(pl.program_id(0) == 0)
@@ -285,10 +344,10 @@ def _kernel(obj: Objective, idx_s, idx_ref, val_ref, y_ref, a_ref, q_ref,
     # 2. in-bucket recursion entirely on VMEM-resident state
     deltas = _bucket_recursion(
         obj, idx_s, idx_ref[...], val_ref, y_ref[...].astype(jnp.float32),
-        a0, q_ref[...].astype(jnp.float32), lam_n, sig, w_ref, u_ref)
+        a0, q_ref[...].astype(jnp.float32), lam_n, sig, w_ref)
 
-    # 3. scatter back into v ONCE per bucket
-    _scatter_rows(idx_s, u_ref, lambda p, u: _add_v(v_ref, p, u))
+    # 3. write W back into v ONCE per bucket
+    _write_back(idx_s, w_ref, lambda p, x: _put_v(v_ref, p, x))
     aout_ref[...] = (a0 + deltas).astype(aout_ref.dtype)
 
 
@@ -368,8 +427,7 @@ def sdca_sparse_bucket_kernel(obj: Objective, idx: Array, val: Array,
             jax.ShapeDtypeStruct((nb, 1, B), ab.dtype),
             jax.ShapeDtypeStruct(v0.shape, jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((B, nnz), jnp.float32),
-                        pltpu.VMEM((B, nnz), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((B, nnz), jnp.float32)],
         input_output_aliases={7: 1},   # v0 buffer reused as v_final
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -408,8 +466,8 @@ def _gather_slice_kernel(idx_s, lo_ref, v_ref, w_ref):
 
 def _sharded_kernel(obj: Objective, idx_s, idx_ref, val_ref, y_ref, a_ref,
                     q_ref, w_in_ref, scal_ref, lo_ref, v_hbm, aout_ref,
-                    v_ref, w_ref, u_ref):
-    """One bucket's recursion + owned-slice scatter, given the
+                    v_ref, w_ref):
+    """One bucket's recursion + owned-slice write-back, given the
     EXCHANGED working set W (full bits on every lane)."""
     pltpu.sync_copy(v_hbm, v_ref)
     w_ref[...] = w_in_ref[...].astype(jnp.float32)
@@ -422,16 +480,15 @@ def _sharded_kernel(obj: Objective, idx_s, idx_ref, val_ref, y_ref, a_ref,
     deltas = _bucket_recursion(
         obj, idx_s, idx_ref[...], val_ref, y_ref[...].astype(jnp.float32),
         a0, q_ref[...].astype(jnp.float32), scal_ref[0], scal_ref[1],
-        w_ref, u_ref)
+        w_ref)
 
-    # scatter the OWNED entries in visiting order; unowned writes put
-    # the unchanged row back (no FP op), so each v entry accumulates its
-    # hits in exactly the replicated kernel's sequence on its one owner
-    def add(p, u):
+    # write back the OWNED entries; an unowned entry stores nothing, so
+    # each v entry gets on its one owner the replicated kernel's bits
+    def put(p, x):
         q, ok = _owned(p, lo, d_loc)
-        _add_v(v_ref, q, u, ok)
+        _put_v(v_ref, q, x, ok)
 
-    _scatter_rows(idx_s, u_ref, add)
+    _write_back(idx_s, w_ref, put)
     aout_ref[...] = (a0 + deltas).astype(aout_ref.dtype)
 
 
@@ -501,7 +558,7 @@ def sdca_sparse_sharded_bucket(obj: Objective, idx_t: Array, val_t: Array,
                                lo: Array, interpret: bool = False,
                                source: str = "ad-hoc arrays"
                                ) -> tuple[Array, Array]:
-    """Run ONE bucket's recursion + owned scatter on the v slice.
+    """Run ONE bucket's recursion + owned write-back on the v slice.
 
     idx_t/val_t: (B, nnz); y_t/a_t/q_t: (B,); W: (B, nnz) the EXCHANGED
     full working set (every lane the same bits); v_loc: (d_loc / 128,
@@ -522,8 +579,7 @@ def sdca_sparse_sharded_bucket(obj: Objective, idx_t: Array, val_t: Array,
             jax.ShapeDtypeStruct((1, B), a_t.dtype),
             jax.ShapeDtypeStruct(v_loc.shape, jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((B, nnz), jnp.float32),
-                        pltpu.VMEM((B, nnz), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((B, nnz), jnp.float32)],
         input_output_aliases={9: 1},   # v slice reused as output
         interpret=interpret,
     )(idx_t, idx_t, val_t, y_t[None], a_t[None], q_t[None], W, scal,

@@ -134,6 +134,30 @@ def _sparse_data(obj, n, d, nnz, seed, v_scale=0.1):
             jnp.asarray(a), jnp.asarray(v0))
 
 
+def _aliased(idx, val, v0, hot, neg_zero):
+    """Rows that all hold the `hot` features first (feature 0 real)
+    and end with a zero-valued feature-0 padding entry: every bucket's
+    alias pass and write-back meet the same few features in every row.
+    `neg_zero` puts -0.0 into v at the hot features and at a quarter of
+    the touched ones."""
+    idx, val, v0 = np.array(idx), np.array(val), np.array(v0)
+    idx[:, :len(hot)] = hot
+    val[:, 0] = np.where(val[:, 0] == 0, 0.5, val[:, 0])
+    idx[:, -1], val[:, -1] = 0, 0.0
+    val = zero_duplicates(idx, val)
+    if neg_zero:
+        touched = np.unique(idx)
+        v0[touched[::4]] = -0.0
+        v0[list(hot)] = -0.0
+    return jnp.asarray(idx), jnp.asarray(val), jnp.asarray(v0)
+
+
+def _assert_bits(got, want):
+    """Equal bit patterns: unlike assert_array_equal, -0.0 != +0.0."""
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
 def _run_both(obj, idx, val, y, a, v0, lam_n, sig, B):
     a_ref, dv_ref = core_sdca.sparse_local_subepoch(
         obj, idx, val, y, a, v0, jnp.float32(lam_n), jnp.float32(sig))
@@ -145,18 +169,31 @@ def _run_both(obj, idx, val, y, a, v0, lam_n, sig, B):
 
 
 @pytest.mark.parametrize("obj", OBJS, ids=lambda o: o.name)
-@pytest.mark.parametrize("n,d,nnz,B", [
-    (32, 64, 8, 8),       # minimal tile
-    (64, 128, 16, 8),     # wider rows, several buckets
-    (64, 32, 8, 16),      # tiny d: heavy feature sharing inside buckets
-    (48, 1000, 8, 8),     # nearly collision-free rows
+@pytest.mark.parametrize("n,d,nnz,B,hot,neg_zero", [
+    # minimal tile
+    pytest.param(32, 64, 8, 8, (), False, id="32-64-8-8"),
+    # wider rows, several buckets
+    pytest.param(64, 128, 16, 8, (), False, id="64-128-16-8"),
+    # tiny d: heavy feature sharing inside buckets
+    pytest.param(64, 32, 8, 16, (), False, id="64-32-8-16"),
+    # nearly collision-free rows
+    pytest.param(48, 1000, 8, 8, (), False, id="48-1000-8-8"),
+    # criteo's width, fully unrolled loops: four features in every row
+    pytest.param(32, 300, 40, 8, (0, 1, 2, 3), False, id="hot-40"),
+    # ... with -0.0 in v where the rows touch it
+    pytest.param(32, 300, 40, 8, (0, 1, 2, 3), True, id="hot-40-negzero"),
+    # wider than UNROLL_NNZ: the blocked loop form
+    pytest.param(32, 300, 136, 8, (0, 1, 2, 3), True, id="hot-136-negzero"),
 ])
-def test_sdca_sparse_kernel_bitwise_vs_scan(obj, n, d, nnz, B):
+def test_sdca_sparse_kernel_bitwise_vs_scan(obj, n, d, nnz, B, hot,
+                                            neg_zero):
     idx, val, y, a, v0 = _sparse_data(obj, n, d, nnz, seed=n * 7 + d)
+    if hot:
+        idx, val, v0 = _aliased(idx, val, v0, hot, neg_zero)
     a_ref, dv_ref, a_k, dv_k = _run_both(
         obj, idx, val, y, a, v0, 0.1 * n, 2.0, B)
-    np.testing.assert_array_equal(a_k, a_ref)
-    np.testing.assert_array_equal(dv_k, dv_ref)
+    _assert_bits(a_k, a_ref)
+    _assert_bits(dv_k, dv_ref)
     assert np.abs(dv_k).max() > 0          # actually moved
 
 
@@ -382,14 +419,23 @@ def test_sdca_sparse_sharded_single_lane_bitwise(obj, n, d, nnz, B):
 
 
 @pytest.mark.parametrize("obj", OBJS, ids=lambda o: o.name)
-@pytest.mark.parametrize("M", [2, 4])
-def test_sdca_sparse_sharded_multilane_emulated_exchange(obj, M):
+@pytest.mark.parametrize("M,d,hot", [
+    # d=50: uneven slices + padding
+    pytest.param(2, 50, (), id="2"),
+    pytest.param(4, 50, (), id="4"),
+    # every row touches features on both sides of the slice boundary
+    # at 2048, and feature 0 both real and padded
+    pytest.param(2, 2100, (0, 1, 2047, 2048, 2099), id="2-boundary"),
+])
+def test_sdca_sparse_sharded_multilane_emulated_exchange(obj, M, d, hot):
     """Drive the per-bucket kernel pair lane by lane with the engine's
     all-gather/owner-select exchange emulated in jnp: the M lanes'
     disjoint dv slices, concatenated, must equal the serial scan's dv
     bitwise, and every lane must agree on the duals."""
-    n, d, nnz, B = 32, 50, 8, 16       # d=50: uneven slices + padding
+    n, nnz, B = 32, 8, 16
     idx, val, y, a, v0 = _sparse_data(obj, n, d, nnz, seed=3 + M)
+    if hot:
+        idx, val, v0 = _aliased(idx, val, v0, hot, neg_zero=True)
     lam_n, sig = jnp.float32(0.1 * n), jnp.float32(2.0)
     a_ref, dv_ref = core_sdca.sparse_local_subepoch(
         obj, idx, val, y, a, v0, lam_n, sig)
@@ -425,10 +471,12 @@ def test_sdca_sparse_sharded_multilane_emulated_exchange(obj, M):
         a_rows.append(a_lanes[0])
     dv = jnp.concatenate(
         [(v_locs[k] - v0_locs[k]).reshape(-1) for k in range(M)])[:d] / sig
-    np.testing.assert_array_equal(np.concatenate(a_rows),
-                                  np.asarray(a_ref))
-    np.testing.assert_array_equal(np.asarray(dv), np.asarray(dv_ref))
+    _assert_bits(np.concatenate(a_rows), a_ref)
+    _assert_bits(dv, dv_ref)
     assert np.abs(np.asarray(dv)).max() > 0
+    if hot:     # both lanes own touched features
+        assert all(np.abs(np.asarray(dv)[k * d_loc:(k + 1) * d_loc]).max()
+                   > 0 for k in range(M))
 
 
 def test_sdca_sparse_sharded_kernel_guards():

@@ -105,6 +105,16 @@ def test_sharded_pair_compiles_at_webspam_slice(one_chip):
              idx, vals, row, row, row, vals, v_loc, _sds(sh, (2,)), lo)
 
 
+@pytest.mark.parametrize("nnz,form", [
+    (40, (1, 40)),           # criteo: every entry straight-line
+    (3728, (466, 8)),        # webspam: a rolled loop of 8-entry blocks
+])
+def test_sparse_entry_loop_form(nnz, form):
+    """The sparse kernel's nnz loops take their form from nnz alone:
+    full unroll up to `UNROLL_NNZ`, blocks of `ENTRY_BLOCK` above."""
+    assert ssb.entry_loop_form(nnz) == form
+
+
 @pytest.fixture
 def vmem_limit(monkeypatch):
     """set(limit): compile the next kernels under that scoped-VMEM
