@@ -28,7 +28,7 @@ def _streamed_mesh_rows(quick: bool) -> list[dict]:
     Needs >= 2 devices (the bench-smoke CI job forces host devices);
     fewer skip the arm, same convention as fig6's sharded arm.  The
     streamed row reports both the MEASURED per-epoch ingest bytes
-    (`MeshChunkFeed.bytes_h2d`) and the planner's modeled quantity
+    (`MeshChunkFeed.bytes_h2d`) and the modeled quantity
     (`planner.streamed_transfer_bytes`, summed over workers) so CI
     can watch the model and the pipeline stay in agreement.
     """
@@ -88,13 +88,6 @@ def _streamed_mesh_rows(quick: bool) -> list[dict]:
         hidden.append(stats["transfer_hidden_frac"])
         wait += stats["ingest_wait_s"]
     wall = time.perf_counter() - t0
-    sig = planner.WorkloadSignature(n=n, d=d, streamed=True)
-    topo = planner.Topology(backend=jax.default_backend(),
-                            device_count=mesh.size,
-                            pods=1, lanes=STREAM_LANES)
-    plan = planner.SolverPlan(solver="xla", route="xla",
-                              bucket=STREAM_BUCKET, chunks=STREAM_CHUNKS,
-                              nnz_multiple=8, feature_shard=False)
     rows.append(dict(
         bench="fig4", dataset="dense-streamed", lanes=STREAM_LANES,
         solver="streamed_mesh", s_per_epoch=wall / epochs,
@@ -102,8 +95,8 @@ def _streamed_mesh_rows(quick: bool) -> list[dict]:
         transfer_hidden_frac=float(np.mean(hidden)),
         ingest_wait_s=wait / epochs,
         h2d_bytes_epoch=epoch_fn.feed.bytes_h2d / epochs,
-        h2d_bytes_model=planner.streamed_transfer_bytes(sig, topo, plan)
-        * topo.workers))
+        h2d_bytes_model=planner.streamed_transfer_bytes(
+            n, d, workers=STREAM_LANES) * STREAM_LANES))
     return rows
 
 

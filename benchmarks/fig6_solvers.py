@@ -108,72 +108,6 @@ def _sparse_rows(quick: bool) -> list[dict]:
     return rows
 
 
-# -- planner arm: $REPRO_PLAN=probe geometry search on the criteo shape -----
-
-
-def _planner_rows(quick: bool) -> list[dict]:
-    """Race the system-aware planner's chosen geometry (DESIGN.md S13)
-    on the criteo-shaped sparse subsample: a probe-mode search (timed
-    1-epoch probes over the analytic top candidates, plan cached in a
-    throwaway dir) picks (bucket, chunks), then the full fit runs under
-    that geometry.  The row carries the chosen `SolverPlan` under the
-    non-CSV "plan" key, which run.py lifts into the BENCH json next to
-    examples/s — so CI tracks WHAT the planner picked, not just how
-    fast it ran."""
-    import os
-    import tempfile
-
-    from repro.core import planner
-
-    epochs = 2 if quick else 6
-    data = load("criteo")
-    idx, val, y = data["X"][0], data["X"][1], data["y"]
-    n, nnz = idx.shape
-    d = data["d"]
-    blk = SPARSE_LANES * SPARSE_LANES * SPARSE_CHUNKS * SPARSE_BUCKET
-    ntr = (int(n * 0.8) // blk) * blk
-    idx, val, y = idx[:ntr], val[:ntr], y[:ntr]
-
-    def fit_timed(bucket, chunks, n_epochs):
-        cfg = EngineConfig.make(
-            lanes=SPARSE_LANES, bucket=bucket, chunks=chunks,
-            partition="dynamic", deterministic=True, local_solver="auto")
-        ses = Session((idx, val), y, objective="logistic", lam=LAM,
-                      cfg=cfg, d=d)
-        ses._epoch_fn(ses.alpha, ses.v, jnp.int32(0))    # warm the jit
-        t0 = time.perf_counter()
-        ses.fit(max_epochs=n_epochs, tol=0.0)
-        return time.perf_counter() - t0, ses
-
-    import jax
-    sig = planner.WorkloadSignature(n=ntr, d=d, nnz=nnz, sparse=True,
-                                    name="criteo-sub")
-    topo = planner.Topology(backend=jax.default_backend(),
-                            device_count=jax.device_count(),
-                            lanes=SPARSE_LANES)
-    with tempfile.TemporaryDirectory() as td:
-        prev = os.environ.get("REPRO_PLAN")
-        os.environ["REPRO_PLAN"] = "probe"
-        try:
-            plan = planner.resolve_plan(
-                sig, topo, cache_dir=td,
-                probe_fn=lambda p: fit_timed(p.bucket, p.chunks, 1)[0])
-        finally:
-            if prev is None:
-                os.environ.pop("REPRO_PLAN", None)
-            else:
-                os.environ["REPRO_PLAN"] = prev
-    wall, ses = fit_timed(plan.bucket, plan.chunks, epochs)
-    return [dict(
-        bench="fig6", dataset="criteo-sparse",
-        solver="sdca_sparse_planner", wall_s=wall, primal=ses.primal(),
-        examples_per_s=ntr * epochs / wall,
-        hbm_bytes_epoch=_sparse_hbm_bytes(
-            ntr, nnz, d, "pallas" if plan.solver == "pallas" else "xla",
-            chunks=plan.chunks),
-        plan=plan.to_json())]
-
-
 # -- feature-sharded sparse arm: webspam-shape d on a model-axis mesh -------
 
 SHARDED_D = 2_101_248        # past the replicated kernel's resident-v
@@ -342,7 +276,6 @@ def run(quick: bool = False):
                              speedup_vs_lbfgs=results["lbfgs"][0] / wall,
                              **parity.get(solver, {})))
     rows.extend(_sparse_rows(quick))
-    rows.extend(_planner_rows(quick))
     rows.extend(_sharded_sparse_rows(quick))
     return emit(rows, HEADER)
 

@@ -27,14 +27,15 @@ from . import (fig1_wild_convergence, fig2_scaling_partitions,
 # the >20% regression gate.  v2: fig3/fig6 sklearn+estimator arms.
 # v3: fig6 sparse xla-vs-pallas arms + deduped synthetic sparse rows.
 # v4: fig6 feature-sharded sparse arm (webspam-shaped, model-axis mesh).
-# v5: fig6 planner arm ($REPRO_PLAN=probe geometry search, chosen
+# v5: fig6 planner arm (probe-mode geometry search, chosen
 #     SolverPlan emitted under figures[...]["plans"]).
 # v6: resilience arm (journal + kill-and-resume recovery overhead,
 #     emitted under figures[...]["recovery"]).
 # v7: fig4 streamed-mesh arm (resident vs MeshChunkFeed-streamed epochs:
 #     transfer-hidden fraction, ingest bytes measured + modeled) and
 #     roofline t_h2d_s column.
-WORKLOAD_VERSION = 7
+# v8: fig6 planner arm removed (the geometry search it timed is gone).
+WORKLOAD_VERSION = 8
 
 BENCHES = [
     ("fig1_wild_convergence", fig1_wild_convergence),
@@ -104,15 +105,6 @@ def main(argv=None) -> int:
                for r in rows if r.get("examples_per_s") is not None]
         if thr:
             figures[name]["throughput"] = thr
-        # chosen SolverPlans from planner arms (fig6) land next to the
-        # throughput records: CI tracks WHAT the planner picked (bucket,
-        # chunks, route, probe seconds), not just how fast it ran
-        plans = [{"dataset": r.get("dataset"), "solver": r.get("solver"),
-                  "examples_per_s": r.get("examples_per_s"),
-                  "plan": r["plan"]}
-                 for r in rows if r.get("plan") is not None]
-        if plans:
-            figures[name]["plans"] = plans
         # recovery-overhead ratios from the resilience arm: CI watches
         # the fault-free hot loop stay free and resume stay ~one-epoch
         recovery = [{k: r.get(k) for k in ("variant", "wall_s",

@@ -20,7 +20,7 @@ pallas-sharded route (four model lanes) against its masked-XLA twin.
 Fails when no TPU is present, when a Pallas phase's epoch program has
 no `tpu_custom_call`, when the gap does not fall, when the kernel and
 XLA routes' final gaps differ by more than 1e-3 relative, or when an
-auto-fallback or planner-fallback warning fires.  Times printed are
+auto-fallback warning fires.  Times printed are
 smoke-test readings, not benchmark results.  The last line of stdout
 is the JSON verdict.
 """
@@ -42,7 +42,7 @@ EPOCHS = 3
 CRITEO_N, HIGGS_N = 262_144, 1_048_576
 MESH_N, SHARDED_N = 131_072, 16_384
 # published widths; the sharded phase's d is chosen so that v is over
-# the replicated kernel's VMEM budget and the planner shards it
+# the replicated kernel's VMEM budget and the static rule shards it
 CRITEO_D, HIGGS_D, SHARDED_D, NNZ = 1_000_000, 28, 4_194_304, 40
 
 
@@ -55,12 +55,9 @@ def fail(msg: str) -> None:
 
 
 def _fallbacks_are_errors() -> None:
-    # the engine's auto->xla misfit reroute and the planner's
-    # infeasible/failed-plan fallbacks must not hide a kernel that
-    # cannot run
-    for pat in ("local_solver='auto'", "planner produced an infeasible",
-                "solver planner failed"):
-        warnings.filterwarnings("error", message=pat)
+    # the engine's auto->xla misfit reroute must not hide a kernel
+    # that cannot run
+    warnings.filterwarnings("error", message="local_solver='auto'")
 
 
 def _program_text(session) -> tuple[str, float]:
@@ -174,11 +171,10 @@ def four_chips() -> None:
     import jax.numpy as jnp
     import numpy as np
     from repro.api import Session
-    from repro.core import EngineConfig, engine
+    from repro.core import EngineConfig, engine, planner
     from repro.core.objectives import LOGISTIC
     from repro.data import make_sparse_classification, registry
     from repro.data.cache import ArrayFeed
-    from repro.kernels import ops as kops
     from repro.launch import glm
     from repro.launch.mesh import make_host_mesh
 
@@ -228,9 +224,10 @@ def four_chips() -> None:
     (idx, val), y, _ = make_sparse_classification(n=n, d=d, nnz=NNZ,
                                                   seed=5, skew=1.1)
     mesh = make_host_mesh(data=1, model=M)
-    route, why = kops.sparse_solver_plan(n, NNZ, d, B, model_lanes=M)
-    if route != "pallas-sharded":
-        fail(f"feature-sharded phase planned {route} ({why})")
+    plan = planner.resolve_plan(sparse=True, n=n, d=d, nnz=NNZ, bucket=B,
+                                n_local=n, model_lanes=M)
+    if plan.route != "pallas-sharded":
+        fail(f"feature-sharded phase planned {plan.route} ({plan.reason})")
     out = {}
     for solver in ("auto", "xla"):
         sc = glm.GLMScale("sharded", "sparse", n=n, d=d, nnz=NNZ,

@@ -12,8 +12,8 @@ Two layers, one report:
 * **Layer 2 — repo lint + budget audit** (`lint`, `budget`): AST rules
   ruff cannot express (kernel-contract registration, collective
   allowlist markers, unseeded RNG, CSR-invariant altitudes) plus an
-  offline sweep proving no plan the planner can emit busts the
-  kernels' VMEM budgets.
+  offline sweep proving no plan `core.planner.resolve_plan` resolves
+  over a grid of geometries busts the kernels' VMEM budgets.
 
 `runner.run_audit` orchestrates both and emits the machine-readable
 report; `selftest.run_selftests` mutates each invariant and proves the

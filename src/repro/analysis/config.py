@@ -57,7 +57,7 @@ COLLECTIVE_CALL_NAMES = frozenset({
 #: The marker an error-swallowing broad except handler must carry
 #: (LINT-BARE-EXCEPT), on the ``except`` line or the line above, with
 #: a short justification after it:
-#:     except Exception:   # audit: except-ok stale plan cache entry
+#:     except Exception:   # audit: except-ok corrupt tile cache entry
 EXCEPT_MARKER = "audit: except-ok"
 
 #: Files that must each contain a raise_on_duplicate_nonzeros call —

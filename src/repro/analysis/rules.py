@@ -81,8 +81,8 @@ RULES: dict[str, Rule] = {r.id: r for r in (
         LINT_KERNEL_CONTRACT, "lint",
         "Every Pallas kernel entry point in the live kernels must be "
         "registered in kernels/contracts.py with a misfit predicate "
-        "and a vmem_bytes_estimate* model, so trace-time routing and "
-        "the planner can never meet an unbudgeted kernel.",
+        "and a vmem_bytes_estimate* model, so the route decision "
+        "(core.planner) can never meet an unbudgeted kernel.",
         "PR 4's review rounds: kernels without misfit predicates "
         "failed at epoch build (or as opaque Mosaic OOMs) instead of "
         "routing to the XLA path at trace time."),
@@ -131,8 +131,9 @@ RULES: dict[str, Rule] = {r.id: r for r in (
         "state corruption."),
     Rule(
         VMEM_PLAN_BUDGET, "budget",
-        "No plan the planner can emit (any candidate geometry over "
-        "any registry workload x topology) may claim a pallas route "
+        "No plan core.planner.resolve_plan resolves (any geometry of "
+        "the audit grid over any registry workload x model-lane "
+        "count) may claim a pallas route "
         "whose kernel VMEM estimate exceeds TOTAL_VMEM_BUDGET_BYTES "
         "or whose resident vector busts V_VMEM_BUDGET_BYTES.",
         "Pre-PR-4 wide tiles (e.g. B=16, nnz=512) surfaced as opaque "

@@ -112,21 +112,18 @@ def check_nondet_prim() -> None:
 
 def check_plan_budget() -> None:
     """Forged over-budget pallas plan -> VMEM-PLAN-BUDGET; the same
-    geometry routed honestly (through candidate enumeration) passes."""
-    from repro.core.planner import (SolverPlan, Topology,
-                                    WorkloadSignature, static_plan)
+    geometry routed honestly (through `planner.resolve_plan`) passes."""
+    from repro.core.planner import SolverPlan, resolve_plan
     # (B=512, nnz=2048): the double-buffered idx/val tiles alone are
     # 2*2*512*2048*4 B = 16 MiB
-    sig = WorkloadSignature(n=4096, d=64, nnz=2048, sparse=True,
-                            name="selftest-forged")
-    topo = Topology(backend="tpu")
+    shape = dict(sparse=True, n=4096, d=64, nnz=2048)
     forged = SolverPlan(solver="pallas", route="pallas-replicated",
                         bucket=512, chunks=1, nnz_multiple=0,
                         feature_shard=False)
-    _expect(budget.audit_plan(sig, topo, forged),
+    _expect(budget.audit_plan(forged, **shape, name="selftest-forged"),
             rules.VMEM_PLAN_BUDGET, "forged over-budget plan")
-    honest = static_plan(sig, topo, bucket=512)
-    _expect_clean(budget.audit_plan(sig, topo, honest),
+    honest = resolve_plan(**shape, bucket=512, backend="tpu")
+    _expect_clean(budget.audit_plan(honest, **shape),
                   f"honestly routed plan ({honest.route})")
 
 

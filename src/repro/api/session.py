@@ -141,19 +141,14 @@ def _check_sparse_kernel_invariant(spec: EngineConfig, idx: np.ndarray,
     Session bucket kwarg overrides the config.
     """
     kind = spec.algo.local_solver
-    if kind not in ("pallas", "auto"):
-        return
     if kind == "auto":
-        kind, explicit = engine._resolve_auto()
-        if kind != "pallas":
-            return
-        if not explicit:
-            from repro.kernels import ops as kops
-            B = max(bucket, 1)
-            # n_local=B: divisibility is guaranteed by Session padding,
-            # so only the shape/budget misfits matter here
-            if kops.sparse_kernel_misfit(B, idx.shape[1], d, B):
-                return   # engine falls back to the XLA scan per-workload
+        from repro.core import planner
+        # the engine runs the kernel only where the plan routes to one
+        kind = planner.resolve_plan(
+            sparse=True, n=idx.shape[0], d=d, nnz=idx.shape[1],
+            bucket=max(bucket, 1)).solver
+    if kind != "pallas":
+        return
     from repro.data.formats import raise_on_duplicate_nonzeros
     raise_on_duplicate_nonzeros(idx, val, "ad-hoc sparse rows")
 
@@ -195,7 +190,7 @@ class Session:
         self.stream_stats: dict[str, float] = {}
         self.cache = None
         self.feed = None
-        self.solver_plan = None       # set when "auto" routes via planner
+        self.solver_plan = None       # set when "auto" resolves a plan
         self.history: list[dict[str, float]] = []
         # resilience runtime (DESIGN.md S15) — all opt-in, all zero
         # overhead when left at the defaults.  `health` is a
@@ -279,33 +274,16 @@ class Session:
         algo = self.spec.algo
         force = bucket if bucket is not None else (algo.bucket or None)
         B = force if force else 1
-        # local_solver="auto" routes through the system-aware planner
-        # (DESIGN.md S13).  Under the default $REPRO_PLAN=on|off the
-        # geometry below stays bitwise today's static resolution (the
-        # plan only records the route); $REPRO_PLAN=search|probe lets
-        # the planner pick bucket/chunks when the caller left them at
-        # the defaults (bucket kwarg unset and algo.bucket <= 1).
+        # local_solver="auto" records the route it resolves to
+        # (DESIGN.md S13); the geometry stays the caller's
         self.solver_plan = None
-        from repro.core import planner
-        if (algo.local_solver == "auto" and (not sparse or d is not None)
-                and planner.plan_mode() != "off"):
-            open_geom = ((bucket is None and (algo.bucket or 1) == 1)
-                         and planner.plan_mode() in ("search", "probe"))
-            sig = planner.WorkloadSignature(
-                n=int(y.shape[0]),
+        if algo.local_solver == "auto" and (not sparse or d is not None):
+            from repro.core import planner
+            self.solver_plan = planner.resolve_plan(
+                sparse=sparse, n=int(y.shape[0]),
                 d=int(d) if sparse else int(np.shape(data)[0]),
                 nnz=int(np.shape(data[0])[1]) if sparse else 0,
-                sparse=sparse)
-            self.solver_plan = planner.resolve_plan(
-                sig, planner.Topology.detect(self.spec),
-                bucket=None if open_geom else B,
-                chunks=None if open_geom else algo.chunks)
-            if open_geom:
-                force = B = self.solver_plan.bucket
-                if self.solver_plan.chunks != algo.chunks:
-                    algo = dataclasses.replace(
-                        algo, chunks=self.solver_plan.chunks)
-                    self.spec = dataclasses.replace(self.spec, algo=algo)
+                bucket=B, chunks=algo.chunks)
         idx = val = X = None
         if sparse:
             idx = np.asarray(data[0], np.int32)
@@ -607,8 +585,8 @@ class Session:
 
     def _switch_local_solver(self, kind: str) -> None:
         """Reroute the local solver (the health guard's pallas→xla
-        fallback — `_auto_fallback`'s warn-and-reroute idiom, made
-        stateful) and rebuild the epoch program."""
+        fallback — `engine._auto_fallback`'s warn-and-reroute idiom,
+        made stateful) and rebuild the epoch program."""
         algo = dataclasses.replace(self.spec.algo, local_solver=kind)
         self.spec = dataclasses.replace(self.spec, algo=algo)
         self._rebuild_epoch_fn()

@@ -26,8 +26,8 @@ Aggregation = Literal["wild", "adding", "averaging"]
 
 #: local-solver implementations the engine can dispatch to, on BOTH the
 #: dense and sparse paths.  "auto" resolves to "pallas" on TPU backends
-#: and "xla" elsewhere ($REPRO_LOCAL_SOLVER overrides either way — see
-#: engine.resolve_auto_solver).
+#: and "xla" elsewhere (`core.planner.auto_solver`), per workload
+#: falling back to "xla" where no kernel fits (`planner.resolve_plan`).
 LocalSolverKind = Literal["auto", "xla", "pallas"]
 
 

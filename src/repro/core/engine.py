@@ -22,8 +22,9 @@ parametrized by two seams:
     (`sdca.sparse_local_subepoch`), or sparse Pallas
     (`kernels.ops.sdca_sparse_bucket_subepoch` — the VMEM-resident
     shared-vector kernel over cached CSR tiles, DESIGN.md S11).
-    "auto" picks Pallas on TPU backends and XLA elsewhere; the
-    `$REPRO_LOCAL_SOLVER` env var overrides either way.
+    "auto" picks Pallas on TPU backends and XLA elsewhere, and falls
+    back to XLA where `core.planner.resolve_plan` finds no kernel fits
+    the traced shapes.
 
 Bit-determinism: with `DeploymentConfig.deterministic=True` both
 backends run each worker's sub-epoch UNBATCHED (lax.map in the sim;
@@ -50,7 +51,6 @@ with `lane` counted data-major over the example-parallel axes.
 from __future__ import annotations
 
 import dataclasses
-import os
 import time
 import warnings
 from concurrent.futures import ThreadPoolExecutor
@@ -247,93 +247,37 @@ def sparse_sharded_xla_solver(obj: Objective, lam_n, sig,
     return solve
 
 
-def _resolve_auto() -> tuple[str, bool]:
-    """("xla"|"pallas", explicit?) for `local_solver="auto"` — explicit
-    when the `$REPRO_LOCAL_SOLVER` hatch forced the choice.  The ONLY
-    parser of the env hatch."""
-    env = os.environ.get("REPRO_LOCAL_SOLVER", "").strip().lower()
-    if env:
-        if env not in ("xla", "pallas"):
-            raise ValueError(
-                f"$REPRO_LOCAL_SOLVER={env!r}: must be 'xla' or 'pallas'")
-        return env, True
-    return ("pallas" if jax.default_backend() == "tpu" else "xla"), False
+def _auto_fallback(pallas_solve: LocalSolver, xla_solve: LocalSolver, *,
+                   sparse: bool, bucket: int,
+                   model_lanes: Optional[int] = None,
+                   warn_path: str) -> LocalSolver:
+    """Backend-picked "auto" pallas: at trace time, ask
+    `planner.resolve_plan` for the route of the traced shapes and send
+    misfits to the XLA path instead of raising mid-trace.  A sparse
+    `model_lanes` marks the feature-sharded layout.  An explicit
+    `local_solver="pallas"` skips this and keeps the kernel's
+    actionable errors."""
+    from repro.core import planner
 
-
-def resolve_auto_solver() -> str:
-    """What `local_solver="auto"` means here: "pallas" on TPU backends
-    (dense AND sparse — both kernels exist), "xla" everywhere else.
-    `$REPRO_LOCAL_SOLVER=xla|pallas` overrides in either direction
-    (the escape hatch for unprofiled TPU topologies / forcing the
-    interpret-mode kernel on CPU)."""
-    return _resolve_auto()[0]
-
-
-def _auto_fallback(pallas_solve: LocalSolver, xla_solve: LocalSolver,
-                   misfit: Callable, warn_path: str) -> LocalSolver:
-    """Backend-auto pallas: pre-check the workload's static shapes
-    against the kernel contract at trace time (`misfit(data, v) ->
-    reason | None`) and route misfits to the XLA path instead of
-    raising mid-trace.  Explicit `local_solver="pallas"` (config or
-    $REPRO_LOCAL_SOLVER) skips this and keeps the kernel's actionable
-    errors."""
     def solve(data, y, a, v):
-        why = misfit(data, v)
-        if why is None:
+        if sparse:
+            idx, _ = data
+            n_local, nnz, d = idx.shape[-2], idx.shape[-1], v.shape[-1]
+        else:
+            (d, n_local), nnz = data.shape[-2:], 0
+        plan = planner.resolve_plan(
+            sparse=sparse, n=n_local, d=d, nnz=nnz, bucket=bucket,
+            n_local=n_local, model_lanes=model_lanes or 1,
+            feature_shard=model_lanes is not None)
+        if plan.route != "xla":
             return pallas_solve(data, y, a, v)
         warnings.warn(
             f"local_solver='auto': the {warn_path} Pallas kernel "
-            f"cannot run this workload ({why}); using the XLA path "
-            f"instead.  Set $REPRO_LOCAL_SOLVER=pallas to force the "
+            f"cannot run this workload ({plan.reason}); using the XLA "
+            f"path instead.  Set local_solver='pallas' to force the "
             f"kernel and get the full error.", stacklevel=2)
         return xla_solve(data, y, a, v)
     return solve
-
-
-def _sparse_auto_fallback(obj: Objective, lam_n, sig, bucket: int,
-                          pallas_solve: LocalSolver) -> LocalSolver:
-    from repro.core import planner
-
-    def misfit(data, v):
-        idx, _ = data
-        _, why = planner.route_sparse(
-            idx.shape[-2], idx.shape[-1], v.shape[-1], bucket)
-        return why
-    return _auto_fallback(pallas_solve, sparse_solver(obj, lam_n, sig),
-                          misfit, "sparse")
-
-
-def _sparse_sharded_auto_fallback(obj: Objective, lam_n, sig, bucket: int,
-                                  model_axis: str, model_lanes: int,
-                                  pallas_solve: LocalSolver) -> LocalSolver:
-    """Sharded-layout twin of `_sparse_auto_fallback`: the misfit check
-    carries `model_lanes` (sharded feasibility) and the fallback is the
-    slice-MASKED scan — the layout already commits every lane to owning
-    only its dv slice."""
-    from repro.core import planner
-
-    def misfit(data, v):
-        idx, _ = data
-        _, why = planner.route_sparse(
-            idx.shape[-2], idx.shape[-1], v.shape[-1], bucket,
-            model_lanes=model_lanes)
-        return why
-    return _auto_fallback(
-        pallas_solve,
-        sparse_sharded_xla_solver(obj, lam_n, sig, model_axis,
-                                  model_lanes),
-        misfit, "feature-sharded sparse")
-
-
-def _dense_auto_fallback(obj: Objective, lam_n, sig, bucket: int,
-                         pallas_solve: LocalSolver) -> LocalSolver:
-    from repro.core import planner
-
-    def misfit(X, v):
-        return planner.route_dense(X.shape[-2], X.shape[-1], bucket)
-    return _auto_fallback(pallas_solve,
-                          dense_xla_solver(obj, lam_n, sig, bucket),
-                          misfit, "dense")
 
 
 def make_local_solver(kind: str, obj: Objective, lam_n, sig, *,
@@ -344,39 +288,31 @@ def make_local_solver(kind: str, obj: Objective, lam_n, sig, *,
                       source: str = "ad-hoc arrays") -> LocalSolver:
     """Resolve an `AlgoConfig.local_solver` name to a LocalSolver.
 
-    "auto" resolves via `resolve_auto_solver`: "pallas" on TPU backends
-    for BOTH the dense and sparse paths, "xla" elsewhere, with
-    `$REPRO_LOCAL_SOLVER` as the override.  Unknown kinds are rejected
-    everywhere.  Backend-picked auto's per-workload misfit pre-checks
-    route through `core.planner.route_sparse`/`route_dense` (DESIGN.md
-    S13) — pure delegations to the kernels' own predicates, so plans
-    can never loosen feasibility and `$REPRO_PLAN` never changes the
-    fallback verdicts here.
+    "auto" resolves via `planner.auto_solver`: "pallas" on TPU backends
+    for BOTH the dense and sparse paths, "xla" elsewhere.  Unknown
+    kinds are rejected everywhere.  A backend-picked "pallas" is
+    wrapped in `_auto_fallback`, which asks `planner.resolve_plan` —
+    the kernels' own predicates — for the route of the traced shapes
+    and falls back to "xla" per workload (dense AND sparse) when they
+    violate the kernel contract (alignment, bucket cap, VMEM budgets)
+    instead of failing at epoch build.
 
     Feature sharding: `model_axis` + `model_lanes` on the SPARSE path
     select the sharded-v layout (DESIGN.md S12) — "pallas" runs the
-    model-axis sharded kernel, "xla" the slice-masked scan, and a
-    backend-picked "auto" wraps the kernel with a sharded-feasibility
-    check (`kops.sparse_kernel_misfit(..., model_lanes=...)`) that
-    falls back to the masked scan.  Dense feature sharding (model-axis
-    psum inside the sub-epoch) still has no kernel, as does the legacy
+    model-axis sharded kernel, "xla" the slice-masked scan, and the
+    backend-picked fallback checks sharded feasibility and falls back
+    to the masked scan.  Dense feature sharding (model-axis psum
+    inside the sub-epoch) still has no kernel, as does the legacy
     sparse layout that passes `model_axis` WITHOUT `model_lanes` (the
     model axis as an example axis): a backend-picked "auto" quietly
     keeps the previously-working "xla" route there, while an explicit
-    pallas request (config or env var) raises.  A backend-picked
-    "auto" likewise falls back to "xla" per-workload (dense AND
-    sparse) when the shapes violate the kernel contract (alignment,
-    bucket cap, VMEM budgets) instead of failing at epoch build.
-    `source` labels the data provenance (tile cache vs ad-hoc arrays)
-    in kernel alignment errors.
+    "pallas" raises.  `source` labels the data provenance (tile cache
+    vs ad-hoc arrays) in kernel alignment errors.
     """
-    auto_pick = False
-    if kind == "auto":
-        # backend-picked only if the env hatch is unset: a user-forced
-        # $REPRO_LOCAL_SOLVER=pallas is an explicit request and keeps
-        # the loud failure modes below.
-        kind, explicit = _resolve_auto()
-        auto_pick = not explicit
+    auto_pick = kind == "auto"
+    if auto_pick:
+        from repro.core import planner
+        kind = planner.auto_solver()
     if kind not in ("xla", "pallas"):
         raise ValueError(f"unknown local_solver {kind!r}")
     sharded_sparse = (sparse and model_axis is not None
@@ -390,35 +326,34 @@ def make_local_solver(kind: str, obj: Objective, lam_n, sig, *,
                 "sharding (model-axis psum) on this path yet"
                 + ("; pass model_lanes=... to route the sparse path "
                    "through the sharded-v kernel" if sparse else ""))
-    if sparse:
-        if sharded_sparse:
-            if kind == "pallas":
-                pallas = sparse_sharded_pallas_solver(
-                    obj, lam_n, sig, bucket, model_axis, model_lanes,
-                    interpret=interpret, source=source)
-                if auto_pick:
-                    return _sparse_sharded_auto_fallback(
-                        obj, lam_n, sig, bucket, model_axis,
-                        model_lanes, pallas)
-                return pallas
-            return sparse_sharded_xla_solver(obj, lam_n, sig,
-                                             model_axis, model_lanes)
-        if kind == "pallas":
-            pallas = sparse_pallas_solver(obj, lam_n, sig, bucket,
-                                          interpret=interpret,
-                                          source=source)
-            if auto_pick:
-                return _sparse_auto_fallback(obj, lam_n, sig, bucket,
-                                             pallas)
-            return pallas
-        return sparse_solver(obj, lam_n, sig)
-    if kind == "pallas":
-        pallas = dense_pallas_solver(obj, lam_n, sig, bucket,
-                                     interpret=interpret, source=source)
-        if auto_pick:
-            return _dense_auto_fallback(obj, lam_n, sig, bucket, pallas)
+    if sharded_sparse:
+        xla = sparse_sharded_xla_solver(obj, lam_n, sig, model_axis,
+                                        model_lanes)
+    elif sparse:
+        xla = sparse_solver(obj, lam_n, sig)
+    else:
+        xla = dense_xla_solver(obj, lam_n, sig, bucket,
+                               model_axis=model_axis)
+    if kind == "xla":
+        return xla
+    if sharded_sparse:
+        pallas, path = sparse_sharded_pallas_solver(
+            obj, lam_n, sig, bucket, model_axis, model_lanes,
+            interpret=interpret, source=source), "feature-sharded sparse"
+    elif sparse:
+        pallas, path = sparse_pallas_solver(
+            obj, lam_n, sig, bucket, interpret=interpret,
+            source=source), "sparse"
+    else:
+        pallas, path = dense_pallas_solver(
+            obj, lam_n, sig, bucket, interpret=interpret,
+            source=source), "dense"
+    if not auto_pick:
         return pallas
-    return dense_xla_solver(obj, lam_n, sig, bucket, model_axis=model_axis)
+    return _auto_fallback(
+        pallas, xla, sparse=sparse, bucket=bucket,
+        model_lanes=model_lanes if sharded_sparse else None,
+        warn_path=path)
 
 
 # ---------------------------------------------------------------------------

@@ -190,10 +190,7 @@ def cache_root(cache_dir=None) -> pathlib.Path:
     """Resolve the cache directory: arg > $REPRO_CACHE_DIR > ~/.cache.
 
     Holds the versioned bucket-tile caches (`data.cache`, one
-    subdirectory per materialized workload) and, under ``plans/``, the
-    solver planner's cached `SolverPlan` JSONs (`core.planner`, keyed
-    by dataset x topology fingerprint) — one $REPRO_CACHE_DIR move
-    relocates both.
+    subdirectory per materialized workload).
     """
     if cache_dir is not None:
         return pathlib.Path(cache_dir)

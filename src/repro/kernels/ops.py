@@ -2,7 +2,10 @@
 
 `sdca_bucket_subepoch` is call-compatible with
 `repro.core.sdca.dense_local_subepoch` so the epoch drivers can route
-through the kernel with cfg.use_kernel=True.
+through the kernel with cfg.use_kernel=True.  The kernels' contracts —
+`sparse_solver_plan`, `sparse_kernel_misfit` and `dense_kernel_misfit`
+— live here; `core.planner.resolve_plan`, the one function that
+chooses the local solver's route, calls them.
 """
 from __future__ import annotations
 
@@ -82,9 +85,9 @@ def sparse_solver_plan(n_local: int, nnz: int, d: int, bucket: int, *,
     LightGBM's data-parallel vs feature-parallel decision by
     #feature/#data shape (SNIPPETS.md Snippet 3) with VMEM budgets as
     the thresholds.  Mirrors the wrapper/kernel guards (bucket
-    divisibility, B/nnz sublane alignment, VMEM budgets) so the
-    engine's backend-picked "auto" path and launch/glm.py's layout
-    default can route misfits at trace time instead of raising.
+    divisibility, B/nnz sublane alignment, VMEM budgets) so
+    `core.planner.resolve_plan` can route misfits to the XLA scan
+    instead of raising.
     """
     if bucket <= 0 or n_local % bucket:
         return "xla", Misfit(
@@ -127,40 +130,6 @@ def sparse_solver_plan(n_local: int, nnz: int, d: int, bucket: int, *,
     return "xla", reason
 
 
-def plan_solver(n: int, d: int, *, nnz: int = 0, sparse: bool = False,
-                name: str = "", bucket: int | None = None,
-                chunks: int | None = None,
-                nnz_multiple: int | None = None, model_lanes: int = 1,
-                streamed: bool = False, cache_dir=None, probe_fn=None):
-    """System-aware geometry + route for a workload: -> `SolverPlan`.
-
-    The kernels-side door into `core.planner` (DESIGN.md S13): builds
-    the workload signature from (n, d, nnz, sparse), detects the live
-    topology from the jax backend, and resolves a plan honoring
-    ``$REPRO_PLAN`` (off | on | search | probe) with disk caching per
-    (dataset fingerprint, topology) next to the tile cache.  Knobs
-    passed explicitly (bucket/chunks/nnz_multiple) are never
-    overridden — the planner only decides what was left open — and
-    every emitted plan passes the misfit predicates above (the PR-4
-    never-regress contract; any planner failure degrades warn-and-safe
-    to the static resolution).
-
-    ``streamed=True`` marks the workload as mesh-streamed (DESIGN.md
-    S16): plan scoring adds the host->device ingest term
-    (`planner.streamed_transfer_bytes` over the slow H2D link) and the
-    disk-cache fingerprint gains a ``|st1`` suffix so streamed and
-    resident plans never collide.
-    """
-    from repro.core import planner
-    sig = planner.WorkloadSignature(n=int(n), d=int(d), nnz=int(nnz),
-                                    sparse=bool(sparse), name=name,
-                                    streamed=bool(streamed))
-    topo = planner.Topology.detect(model_lanes=model_lanes)
-    return planner.resolve_plan(sig, topo, bucket=bucket, chunks=chunks,
-                                nnz_multiple=nnz_multiple,
-                                cache_dir=cache_dir, probe_fn=probe_fn)
-
-
 def sparse_kernel_misfit(n_local: int, nnz: int, d: int, bucket: int,
                          model_lanes: int = 1) -> str | None:
     """Why NO sparse Pallas kernel can run this workload, or None.
@@ -182,8 +151,8 @@ def dense_kernel_misfit(d: int, n_local: int, bucket: int) -> str | None:
 
     The dense wrapper below zero-pads d and B to sublane multiples, so
     the only hard misfits are bucket divisibility, the kernel's B cap,
-    and the VMEM footprint of the padded tiles.  Used by the engine's
-    backend-picked "auto" path, like `sparse_kernel_misfit`.
+    and the VMEM footprint of the padded tiles.  Called by
+    `core.planner.resolve_plan`, like `sparse_solver_plan`.
     """
     if bucket <= 0 or n_local % bucket:
         return Misfit(MisfitCode.BUCKET_INDIVISIBLE,

@@ -129,16 +129,14 @@ def scale_for_dataset(name: str, **overrides) -> GLMScale:
     Sizes come from the dataset registry's REAL shapes (not the offline
     sub-samples): n is padded to a 32k multiple and d/nnz to mesh- and
     tile-friendly multiples, mirroring how the hand-written GLM_CONFIGS
-    entries were derived from the paper's tables.  The data layout —
-    and, under ``$REPRO_PLAN=search|probe``, the bucket/chunk geometry
-    — resolves through the system-aware planner (`core.planner`,
-    DESIGN.md S13): wide dense datasets (d >= 512) feature-shard over
-    'model'; sparse datasets do exactly when the replicated shared
-    vector cannot fit the kernel's VMEM budget (webspam-scale d) — the
-    same boundary `kernels.ops.sparse_solver_plan` dispatches on, now
-    written once in `planner.feature_shard_default`.  Explicit
-    overrides always win, and any planner failure degrades
-    warn-and-safe to that static layout rule.
+    entries were derived from the paper's tables.  Bucket 16 and 4
+    chunks are the defaults.  The data layout resolves through
+    `core.planner.resolve_plan` (DESIGN.md S13): wide dense datasets
+    (d >= 512) feature-shard over 'model'; sparse datasets do exactly
+    when the replicated shared vector cannot fit the kernel's VMEM
+    budget (webspam-scale d) — the same boundary
+    `kernels.ops.sparse_solver_plan` dispatches on.  Explicit
+    overrides always win.
     """
     from repro.core import planner
     from repro.data.registry import get_spec
@@ -152,16 +150,11 @@ def scale_for_dataset(name: str, **overrides) -> GLMScale:
     sparse = spec.kind == "sparse"
     if sparse:
         kw["nnz"] = -(-spec.nnz // 8) * 8
-    sig = planner.WorkloadSignature(n=n, d=d, nnz=kw.get("nnz", 0),
-                                    sparse=sparse, name=name)
-    searching = planner.plan_mode() in ("search", "probe")
     plan = planner.resolve_plan(
-        sig, planner.Topology.detect(),
-        bucket=overrides.get("bucket", None if searching else 16),
-        chunks=overrides.get("chunks", None if searching else 4))
+        sparse=sparse, n=n, d=d, nnz=kw.get("nnz", 0),
+        bucket=overrides.get("bucket", 16),
+        chunks=overrides.get("chunks", 4))
     kw["feature_shard"] = plan.feature_shard
-    if searching:
-        kw["bucket"], kw["chunks"] = plan.bucket, plan.chunks
     kw.update(overrides)
     return GLMScale(**kw)
 
@@ -622,21 +615,11 @@ def glm_analytic(scale: GLMScale, mesh, *, streamed: bool = False) -> dict:
            "coll": float(coll), "method": "analytic-closed-form"}
     if streamed:
         from repro.core import planner
-        pods = mesh.shape.get("pod", 1)
-        topo = planner.Topology(
-            backend="tpu", device_count=mesh.size, pods=pods,
-            lanes=W // pods,
+        out["h2d bytes"] = planner.streamed_transfer_bytes(
+            scale.n, scale.d, sparse=scale.kind == "sparse",
+            nnz=scale.nnz, nnz_multiple=8, workers=W,
             model_lanes=(mesh.shape.get("model", 1)
                          if scale.feature_shard else 1))
-        sig = planner.WorkloadSignature(
-            n=scale.n, d=scale.d, nnz=scale.nnz,
-            sparse=scale.kind == "sparse", streamed=True)
-        plan = planner.SolverPlan(
-            solver="xla", route="xla", bucket=scale.bucket,
-            chunks=scale.chunks, nnz_multiple=8,
-            feature_shard=scale.feature_shard)
-        out["h2d bytes"] = planner.streamed_transfer_bytes(
-            sig, topo, plan)
     return out
 
 

@@ -25,8 +25,8 @@ HBM_BW = 819e9               # bytes/s per chip
 ICI_BW = 50e9                # bytes/s per link (~ICI); pod-to-pod is slower
 
 # host->device ingest link (streamed feeds) — defined ONCE in
-# core.planner so the plan score and the roofline agree; re-exported
-# here next to its sibling bandwidths
+# core.planner next to the h2d byte model; re-exported here next to
+# its sibling bandwidths
 from repro.core.planner import H2D_BW  # noqa: E402,F401
 
 

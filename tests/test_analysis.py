@@ -4,6 +4,8 @@ The jaxpr-layer tests shell out with forced host devices (repo
 convention: only launch entrypoints force device counts); the lint,
 budget, and registry tests run in-process — they are stdlib-side.
 """
+import dataclasses
+import json
 import os
 import pathlib
 import subprocess
@@ -215,17 +217,15 @@ def test_rules_registry_complete():
 def test_budget_audit_clean_and_catches_forged_plan():
     sys.path.insert(0, str(REPO / "src"))
     from repro.analysis import budget, rules
-    from repro.core.planner import (SolverPlan, Topology,
-                                    WorkloadSignature)
+    from repro.core.planner import SolverPlan
     found, n_plans = budget.run_budget_audit()
     assert found == [], [str(f) for f in found]
     assert n_plans > 500          # the sweep actually swept
 
-    sig = WorkloadSignature(n=4096, d=64, nnz=2048, sparse=True)
     forged = SolverPlan(solver="pallas", route="pallas-replicated",
                         bucket=512, chunks=1, nnz_multiple=0,
                         feature_shard=False)
-    got = budget.audit_plan(sig, Topology(backend="tpu"), forged)
+    got = budget.audit_plan(forged, sparse=True, n=4096, d=64, nnz=2048)
     assert got and all(f.rule == rules.VMEM_PLAN_BUDGET for f in got)
 
 
@@ -234,8 +234,7 @@ def test_misfit_reasons_carry_stable_codes():
     the planner surfaces the code on SolverPlan.reason_code."""
     sys.path.insert(0, str(REPO / "src"))
     from repro.kernels import ops
-    from repro.core.planner import (Topology, WorkloadSignature,
-                                    static_plan)
+    from repro.core.planner import resolve_plan
 
     route, reason = ops.sparse_solver_plan(100, 8, 64, 16)
     assert route == "xla"
@@ -255,16 +254,15 @@ def test_misfit_reasons_carry_stable_codes():
 
     # planner surface: infeasible geometry -> code on the plan;
     # feasible -> empty code, reason "fits"
-    sig = WorkloadSignature(n=4096, d=64, nnz=2048, sparse=True)
-    plan = static_plan(sig, Topology(backend="tpu"), bucket=512)
+    plan = resolve_plan(sparse=True, n=4096, d=64, nnz=2048, bucket=512,
+                        backend="tpu")
     assert plan.route == "xla"
     assert plan.reason_code == ops.MisfitCode.VMEM_TOTAL
     assert type(plan.reason) is str       # JSON-plain on the record
-    fits = static_plan(WorkloadSignature(n=4096, d=64, nnz=8,
-                                         sparse=True),
-                       Topology(backend="tpu"), bucket=16)
+    fits = resolve_plan(sparse=True, n=4096, d=64, nnz=8, bucket=16,
+                        backend="tpu")
     assert fits.reason == "fits" and fits.reason_code == ""
-    doc = fits.to_json()
+    doc = json.loads(json.dumps(dataclasses.asdict(fits)))
     assert doc["reason_code"] == ""
 
 

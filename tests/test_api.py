@@ -443,10 +443,9 @@ def test_streamed_trainer_shim_warns(tmp_path):
 
 # -- local-solver dispatch (satellite) --------------------------------------
 
-def test_sparse_local_solver_auto_resolves_to_xla(monkeypatch):
+def test_sparse_local_solver_auto_resolves_to_xla():
     # off-TPU (every CI host), "auto" still means the XLA scan; the
-    # TPU->pallas resolution + env hatch are pinned in test_engine.py
-    monkeypatch.delenv("REPRO_LOCAL_SOLVER", raising=False)
+    # TPU->pallas resolution is pinned in test_engine.py
     from repro.core import make_local_solver
     from repro.core.objectives import LOGISTIC
 
@@ -475,7 +474,6 @@ def test_session_rejects_duplicate_nonzeros_for_pallas(monkeypatch):
     from repro.api import Session
     from repro.core.config import EngineConfig
 
-    monkeypatch.delenv("REPRO_LOCAL_SOLVER", raising=False)
     (idx, val), y, d = make_sparse_classification(n=64, d=32, nnz=8,
                                                   seed=5)
     bad_idx = np.asarray(idx).copy()

@@ -8,6 +8,7 @@ import pathlib
 import subprocess
 import sys
 import textwrap
+import types
 
 import jax
 import jax.numpy as jnp
@@ -99,9 +100,8 @@ def test_local_solver_auto_model_axis_falls_back(monkeypatch):
     """On TPU hosts a backend-picked "auto" must keep LEGACY
     feature-sharded (model-axis without model_lanes) launches on the
     previously-working xla route; only an EXPLICIT pallas request
-    (config or env var) raises.  With model_lanes the sparse path has a
-    real sharded kernel now (PR 6) and routes there instead."""
-    monkeypatch.delenv("REPRO_LOCAL_SOLVER", raising=False)
+    raises.  With model_lanes the sparse path has a real sharded kernel
+    now (PR 6) and routes there instead."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     # backend-auto + model_axis: silently xla, not a ValueError.  Pin
     # the actual route via the closure's qualname (the solver can't be
@@ -121,14 +121,13 @@ def test_local_solver_auto_model_axis_falls_back(monkeypatch):
                                sparse=True, model_axis="model",
                                model_lanes=2)
     assert solver.__qualname__.startswith("sparse_sharded_xla_solver")
-    # env-forced pallas is an explicit request: still loud on the
-    # legacy (no-model_lanes) layouts
-    monkeypatch.setenv("REPRO_LOCAL_SOLVER", "pallas")
+    # an explicit pallas request stays loud on the legacy
+    # (no-model_lanes) layouts
     with pytest.raises(ValueError, match="feature sharding"):
-        make_local_solver("auto", LOGISTIC, 1.0, 1.0, bucket=8,
+        make_local_solver("pallas", LOGISTIC, 1.0, 1.0, bucket=8,
                           model_axis="model")
     with pytest.raises(ValueError, match="feature sharding"):
-        make_local_solver("auto", LOGISTIC, 1.0, 1.0, bucket=8,
+        make_local_solver("pallas", LOGISTIC, 1.0, 1.0, bucket=8,
                           sparse=True, model_axis="model")
 
 
@@ -139,7 +138,6 @@ def test_local_solver_auto_sparse_workload_fallback(monkeypatch):
     import numpy as np
     from repro.data import make_sparse_classification
 
-    monkeypatch.delenv("REPRO_LOCAL_SOLVER", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     auto = make_local_solver("auto", LOGISTIC, 1.6, 1.0, bucket=8,
                              sparse=True, interpret=True)
@@ -169,7 +167,6 @@ def test_local_solver_auto_dense_workload_fallback(monkeypatch):
     a warning, and keeps the kernel for fitting ones."""
     import numpy as np
 
-    monkeypatch.delenv("REPRO_LOCAL_SOLVER", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rng = np.random.default_rng(0)
     y = jnp.asarray(rng.choice([-1.0, 1.0], 16).astype(np.float32))
@@ -197,35 +194,92 @@ def test_local_solver_auto_dense_workload_fallback(monkeypatch):
 
 
 def test_local_solver_auto_resolution(monkeypatch):
-    """"auto" = backend-dependent (xla off-TPU) with the
-    $REPRO_LOCAL_SOLVER escape hatch in both directions."""
+    """"auto" = backend-dependent: the XLA scan off the TPU, and on a
+    TPU backend the kernel, bitwise-identical to an explicit
+    local_solver="pallas" where the shapes fit it."""
     import numpy as np
-    from repro.core.engine import resolve_auto_solver
     from repro.data import make_sparse_classification
 
-    monkeypatch.delenv("REPRO_LOCAL_SOLVER", raising=False)
-    assert resolve_auto_solver() == "xla"        # CPU/GPU test hosts
-    monkeypatch.setenv("REPRO_LOCAL_SOLVER", "pallas")
-    assert resolve_auto_solver() == "pallas"
-    monkeypatch.setenv("REPRO_LOCAL_SOLVER", "bogus")
-    with pytest.raises(ValueError, match="REPRO_LOCAL_SOLVER"):
-        resolve_auto_solver()
-
-    # env-forced pallas flows through make_local_solver("auto") and is
-    # bitwise-identical to the explicit kernel solver
-    monkeypatch.setenv("REPRO_LOCAL_SOLVER", "pallas")
     (idx, val), y, d = make_sparse_classification(n=16, d=32, nnz=8,
                                                   seed=0)
     args = ((jnp.asarray(idx), jnp.asarray(val)), jnp.asarray(y),
             jnp.zeros(16), jnp.zeros(d))
-    auto = make_local_solver("auto", LOGISTIC, 1.6, 1.0, bucket=8,
-                             sparse=True)
-    explicit = make_local_solver("pallas", LOGISTIC, 1.6, 1.0, bucket=8,
-                                 sparse=True)
-    a1, dv1 = auto(*args)
-    a2, dv2 = explicit(*args)
-    assert np.array_equal(np.asarray(a1), np.asarray(a2))
-    assert np.array_equal(np.asarray(dv1), np.asarray(dv2))
+
+    def run(kind):
+        a, dv = make_local_solver(kind, LOGISTIC, 1.6, 1.0, bucket=8,
+                                  sparse=True, interpret=True)(*args)
+        return np.asarray(a), np.asarray(dv)
+
+    xla, pallas = run("xla"), run("pallas")
+    for got, want in zip(run("auto"), xla):          # CPU test hosts
+        assert np.array_equal(got, want)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for got, want in zip(run("auto"), pallas):
+        assert np.array_equal(got, want)
+
+
+def _shape(*dims):
+    return types.SimpleNamespace(shape=dims)
+
+
+@pytest.mark.parametrize("sparse,model_lanes,path,misfit,fit", [
+    # nnz=7 is off the sublane tile; nnz=8 fits the replicated kernel
+    (True, None, "sparse",
+     ((_shape(16, 7), None), _shape(32)),
+     ((_shape(16, 8), None), _shape(32))),
+    # over two model lanes a d/2 slice past the resident-v budget has
+    # no kernel; one sublane past the replicated budget fits the
+    # sharded kernel
+    (True, 2, "feature-sharded sparse",
+     ((_shape(16, 8), None), _shape(8_388_608)),
+     ((_shape(16, 8), None), _shape(2_097_160))),
+    # (d_pad, B) tiles over the total VMEM budget; a narrow d fits
+    (False, None, "dense",
+     (_shape(250_000, 16), _shape(250_000)),
+     (_shape(32, 16), _shape(32))),
+])
+def test_auto_fallback_routes_misfits_to_xla(sparse, model_lanes, path,
+                                             misfit, fit):
+    """The one trace-time fallback asks the plan for the traced shapes'
+    route: a misfit runs the XLA solver with the warning naming the
+    layout, a fit runs the kernel with no warning."""
+    import warnings
+    from repro.core import engine
+
+    ran = []
+    solve = engine._auto_fallback(
+        lambda *a: ran.append("pallas"), lambda *a: ran.append("xla"),
+        sparse=sparse, bucket=8, model_lanes=model_lanes, warn_path=path)
+    data, v = misfit
+    with pytest.warns(UserWarning, match=f"the {path} Pallas kernel"):
+        solve(data, None, None, v)
+    data, v = fit
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        solve(data, None, None, v)
+    assert ran == ["xla", "pallas"]
+
+
+@pytest.mark.parametrize("sparse,d,nnz,match", [
+    (True, 32, 7, "multiple"),                 # off the sublane tile
+    (True, 2_097_160, 8, "VMEM budget"),       # v past the resident budget
+    (False, 250_000, 0, "budget"),             # dense tiles over budget
+])
+def test_explicit_pallas_misfit_raises(sparse, d, nnz, match):
+    """An explicit local_solver="pallas" keeps the kernel's actionable
+    error on a misfit instead of falling back."""
+    import numpy as np
+    rng = np.random.default_rng(0)
+    y = jnp.asarray(rng.choice([-1.0, 1.0], 16).astype(np.float32))
+    solver = make_local_solver("pallas", LOGISTIC, 1.6, 1.0, bucket=8,
+                               sparse=sparse, interpret=True)
+    if sparse:
+        idx = jnp.tile(jnp.arange(nnz, dtype=jnp.int32), (16, 1))
+        data = (idx, jnp.ones((16, nnz), jnp.float32))
+    else:
+        data = jnp.zeros((d, 16), jnp.float32)
+    with pytest.raises(ValueError, match=match):
+        solver(data, y, jnp.zeros(16), jnp.zeros(d))
 
 
 def test_chunks_must_divide_buckets():

@@ -9,8 +9,8 @@ nonzero on any finding:
   hazards, unordered reductions);
 * ``lint``   — stdlib AST rules (kernel contract registry, collective
   allowlist markers, unseeded RNG, CSR entry altitudes);
-* ``budget`` — sweep planner candidate geometries against the kernels'
-  own VMEM estimators.
+* ``budget`` — sweep a grid of plans `core.planner.resolve_plan`
+  resolves against the kernels' own VMEM estimators.
 
 ``--selftest`` additionally runs the mutation self-tests (one injected
 bug per rule ID; each must be detected).  Usage::
